@@ -24,11 +24,13 @@ root, machine-readable for the CI artifact):
 
 Candidates are measured *and* served through the same lowering backend
 (``REPRO_BACKEND`` / ``--backend``: ``jnp`` default, ``pallas`` for the
-Pallas kernels in interpret mode on CPU / compiled on TPU) — the
-measured artifact is the dispatched artifact, per-backend.
+Mosaic-compiled Pallas kernels on a TPU, ``pallas-interpret`` for the
+Pallas interpreter on CPU) — the measured artifact is the dispatched
+artifact, per-backend.
 
 Env knobs: ``REPRO_BENCH_TRIALS`` (per-task measurement budget, default
-24), ``REPRO_RUNNER`` (measurement runner spec, default ``cached+pool``),
+24), ``REPRO_RUNNER`` (measurement runner spec, default ``cached+local``:
+in-process, because a chip belongs to one process),
 ``REPRO_BACKEND`` (lowering backend, default ``jnp``),
 ``REPRO_E2E_MODELS`` (comma list, default ``smollm-135m``),
 ``REPRO_E2E_TASKS`` (task cap by weight x flops, default 6 — enough to
@@ -62,6 +64,7 @@ from repro.backends.registry import resolve_backend_spec
 from repro.configs.base import get_config
 from repro.integration.dispatch import DispatchContext
 from repro.integration.extract import extract_task_specs
+from repro.launch.runtime import enable_compile_cache
 from repro.models.registry import build_model
 from repro.search.database import Database
 from repro.search.evolutionary import SearchConfig
@@ -116,7 +119,7 @@ def run(
     backend: str = None,
 ) -> List[Dict]:
     trials = int(os.environ.get("REPRO_BENCH_TRIALS", "24"))
-    runner_spec = os.environ.get("REPRO_RUNNER", "cached+pool")
+    runner_spec = os.environ.get("REPRO_RUNNER", "cached+local")
     backend = resolve_backend_spec(backend)
     if backend != "jnp":
         # per-backend database and report: best-trace selection must come
@@ -199,11 +202,10 @@ def run(
             jnp.int32,
         )
         tuned_ctx = DispatchContext(db, tasks=tasks, mode="best", backend=backend)
-        # cover exactly the keys that compile in *both* contexts: a
+        # cover exactly the keys served in *both* contexts: a
         # stale/corrupt record passes db.best() but fails validation, and
-        # a default schedule can fail a backend's lowering (e.g. the
-        # Pallas grid cap) while the tuned one succeeds — either way the
-        # key must fall back in both contexts or the comparison skews
+        # a space may have no valid default schedule — either way the key
+        # must fall back in both contexts or the comparison skews
         covered = [t for t in tasks if tuned_ctx.kernel(t.key) is not None]
         untuned_ctx = DispatchContext(
             db, tasks=covered, mode="default", backend=backend
@@ -351,6 +353,7 @@ def main(argv=None):
     )
     ap.add_argument("--db", default="results/tuning_db.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run(db_path=args.db, backend=args.backend)
 
 

@@ -20,7 +20,9 @@ def main() -> None:
     ap.add_argument("--only", default="", help="comma-separated section list")
     args = ap.parse_args()
     picked = args.only.split(",") if args.only else SECTIONS
+    from repro.launch.runtime import enable_compile_cache
 
+    enable_compile_cache()
     t0 = time.time()
     print("name,us_per_call,derived")
     if "operators" in picked:  # Figure 8

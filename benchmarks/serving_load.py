@@ -58,6 +58,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.integration.dispatch import DispatchContext
 from repro.integration.extract import extract_decode_task_specs
+from repro.launch.runtime import enable_compile_cache
 from repro.models.registry import build_model
 from repro.search.database import Database
 from repro.search.evolutionary import SearchConfig
@@ -272,6 +273,7 @@ def main(argv=None) -> int:
     ap.add_argument("--retune", action="store_true",
                     help="re-tune decode tasks that already hold records")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     db_path = args.db

@@ -19,7 +19,7 @@ def main():
     args = ap.parse_args()
 
     cfg = get_config("smollm-135m", smoke=True)
-    db = repro.Database("/tmp/tune_and_train_db.json")
+    db = repro.Database("results/tune_and_train_db.json")
 
     print("== phase 1: tune the model's tensor programs (task scheduler) ==")
     # tasks extracted automatically from the model's forward jaxpr —
@@ -38,8 +38,6 @@ def main():
         print(f"  {k}: {v*1e6:.1f} us")
 
     print("\n== phase 2: train with tuned kernels in the database ==")
-    import os
-    os.environ["REPRO_TUNING_DB"] = "/tmp/tune_and_train_db.json"
     with tempfile.TemporaryDirectory() as ckpt_dir:
         losses = train_launcher.main([
             "--arch", "smollm-135m", "--smoke",

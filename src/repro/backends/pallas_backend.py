@@ -10,8 +10,10 @@ become the kernel's fused epilogue.  This is the concrete instantiation of
 "MetaSchedule constructs the space, the backend carries the decisions to
 hardware" (paper Fig 1 + Appendix A.6).
 
-Pallas needs exact tiling, so sampled tile extents are *snapped* to the
-nearest divisor of the problem shape at lower time.  Snapping is part of
+Pallas needs exact tiling and Mosaic needs aligned blocks, so sampled
+tile extents are *snapped* at lower time to the nearest divisor of the
+problem shape that is a multiple of the TPU tile (8 sublanes for a
+block's second-to-last dim, 128 lanes for its last) or the whole dim.  Snapping is part of
 the lowering's provenance: every ``lower_*`` path returns a meta dict with
 both the sampled and the snapped blocks, which the measurement stack
 persists into ``TuningRecord.meta`` and the dispatch layer surfaces on
@@ -32,6 +34,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 from ..core.schedule import BlockNode, LoopNode, Schedule, iter_nodes
 from ..core.tir import PrimFunc
+from ..kernels.flash_attention import LANE, SUBLANE, best_divisor
 from ..kernels.matmul import DEFAULT_BLOCKS
 from ..kernels.softmax import DEFAULT_ROW_BLOCK
 
@@ -109,11 +112,16 @@ def extract_row_block(sch: Schedule) -> Optional[int]:
 
 
 def snap_blocks(
-    dims: Tuple[int, ...], blocks: Tuple[int, ...]
+    dims: Tuple[int, ...], blocks: Tuple[int, ...], aligns: Tuple[int, ...]
 ) -> Tuple[int, ...]:
     """Snap each sampled tile extent to the nearest divisor of its dim
-    (Pallas BlockSpecs need exact tiling)."""
-    return tuple(_best_divisor(d, b) for d, b in zip(dims, blocks))
+    that is a multiple of its alignment, or to the whole dim."""
+    return tuple(best_divisor(d, b, a) for d, b, a in zip(dims, blocks, aligns))
+
+
+# (M, N, K) alignments of a matmul's blocks: bm is the sublane dim of the
+# x block, bn the lane dim of the w and out blocks, bk the lane dim of x
+MATMUL_ALIGNS = (SUBLANE, LANE, LANE)
 
 
 # Reject lowerings whose grid would explode: a 1-wide tile on a 128^3
@@ -137,7 +145,7 @@ def _check_grid(steps: int, blocks) -> None:
 
 
 def lower_dense(
-    sch: Schedule, *, interpret: bool = True
+    sch: Schedule, *, interpret: bool
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned dense (+fused epilogue) via the Pallas matmul kernel."""
     from ..kernels import matmul as mm
@@ -147,7 +155,7 @@ def lower_dense(
     X, W = func.inputs[0], func.inputs[1]
     M, K = X.shape
     N = W.shape[1]
-    blocks = snap_blocks((M, N, K), sampled or DEFAULT_BLOCKS)
+    blocks = snap_blocks((M, N, K), sampled or DEFAULT_BLOCKS, MATMUL_ALIGNS)
     bm, bn, bk = blocks
     _check_grid((M // bm) * (N // bn) * (K // bk), blocks)
     # epilogue from the ORIGINAL workload name (dense_<epilogue>)
@@ -171,7 +179,7 @@ def lower_dense(
 
 
 def lower_batch_matmul(
-    sch: Schedule, *, interpret: bool = True
+    sch: Schedule, *, interpret: bool
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned batched matmul via the Pallas bmm kernel (batch grid dim)."""
     from ..kernels import matmul as mm
@@ -182,7 +190,7 @@ def lower_batch_matmul(
     _, M, K = A.shape
     N = func.inputs[1].shape[2]
     B = A.shape[0]
-    blocks = snap_blocks((M, N, K), sampled or DEFAULT_BLOCKS)
+    blocks = snap_blocks((M, N, K), sampled or DEFAULT_BLOCKS, MATMUL_ALIGNS)
     bm, bn, bk = blocks
     _check_grid(B * (M // bm) * (N // bn) * (K // bk), blocks)
     meta = _block_meta("batch_matmul", sampled, blocks)
@@ -197,7 +205,7 @@ def lower_batch_matmul(
 
 
 def lower_sfm(
-    sch: Schedule, *, interpret: bool = True
+    sch: Schedule, *, interpret: bool
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned row softmax via the Pallas online-softmax kernel."""
     from ..kernels import softmax as sm
@@ -205,7 +213,7 @@ def lower_sfm(
     func = sch.func
     M = func.inputs[0].shape[0]
     sampled = extract_row_block(sch)
-    (bm,) = snap_blocks((M,), (sampled or DEFAULT_ROW_BLOCK,))
+    (bm,) = snap_blocks((M,), (sampled or DEFAULT_ROW_BLOCK,), (SUBLANE,))
     meta = {
         "pallas_kernel": "row_softmax",
         "pallas_rows_sampled": sampled,
@@ -248,14 +256,14 @@ def extract_attention_blocks(sch: Schedule) -> Optional[Tuple[int, int]]:
 
 
 def lower_attention(
-    sch: Schedule, *, interpret: bool = True
+    sch: Schedule, *, interpret: bool
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned fused attention via the Pallas flash kernel.
 
     The schedule's sampled (i, j) tiles of the ``scores`` block become the
-    flash kernel's (block_q, block_kv), snapped to divisors of the
-    sequence length — the same sampled-vs-snapped provenance contract as
-    the matmul tiles.
+    flash kernel's (block_q, block_kv), snapped to aligned divisors of
+    the sequence length — the same sampled-vs-snapped provenance contract
+    as the matmul tiles.
     """
     from ..kernels.flash_attention import flash_attention
 
@@ -264,7 +272,9 @@ def lower_attention(
     b, kvh, g, s, d = Q.shape
     causal, window, softcap = _parse_attention_name(func.name)
     sampled = extract_attention_blocks(sch)
-    blocks = snap_blocks((s, s), sampled or DEFAULT_ATTN_BLOCKS)
+    blocks = snap_blocks(
+        (s, s), sampled or DEFAULT_ATTN_BLOCKS, (SUBLANE, SUBLANE)
+    )
     bq, bkv = blocks
     _check_grid(b * kvh * g * (s // bq) * (s // bkv), blocks)
     meta = _block_meta("flash_attention", sampled, blocks)
@@ -301,14 +311,14 @@ def extract_decode_kv_block(sch: Schedule) -> Optional[int]:
 
 
 def lower_attention_decode(
-    sch: Schedule, *, interpret: bool = True
+    sch: Schedule, *, interpret: bool
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned single-token decode attention via the Pallas decode kernel.
 
     The decode workload has no query tiling (s_q = 1: the GQA group rides
     whole in one tile), so the only tunable block is the kv tile — the
-    sampled ``j`` extent of the ``scores`` block, snapped to a divisor of
-    the cache length.  The dynamic mask arrives as the workload's BIAS
+    sampled ``j`` extent of the ``scores`` block, snapped to a 128-aligned
+    divisor of the cache length (it is the lane dim of the bias block).  The dynamic mask arrives as the workload's BIAS
     input, passed straight through to the kernel.
     """
     from ..kernels.flash_attention import decode_flash_attention
@@ -325,7 +335,7 @@ def lower_attention_decode(
             except ValueError:
                 pass
     sampled = extract_decode_kv_block(sch)
-    (bkv,) = snap_blocks((t,), (sampled or DEFAULT_DECODE_KV_BLOCK,))
+    (bkv,) = snap_blocks((t,), (sampled or DEFAULT_DECODE_KV_BLOCK,), (LANE,))
     _check_grid(b * kvh * (t // bkv), (bkv,))
     meta = _block_meta(
         "decode_flash_attention",
@@ -363,7 +373,7 @@ def _block_meta(kernel: str, sampled, snapped) -> Dict[str, Any]:
 
 
 def lower_to_pallas(
-    sch: Schedule, *, interpret: bool = True
+    sch: Schedule, *, interpret: bool
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Dispatch a supported schedule to its Pallas lowering.
 
@@ -386,18 +396,3 @@ def lower_to_pallas(
         return lower_sfm(sch, interpret=interpret)
     raise ValueError(f"no Pallas lowering for workload {name!r}")
 
-
-def lower_dense_to_pallas(
-    sch: Schedule,
-    *,
-    interpret: bool = True,
-):
-    """Back-compat wrapper: (fn, snapped blocks) for a dense schedule."""
-    fn, meta = lower_dense(sch, interpret=interpret)
-    return fn, tuple(meta["pallas_blocks_snapped"])
-
-
-def _best_divisor(n: int, target: int) -> int:
-    from ..kernels.flash_attention import best_divisor
-
-    return best_divisor(n, target)

@@ -9,9 +9,9 @@ candidates, and the dispatch layer serves models, through the *same*
 selected lowering::
 
     "jnp"               structural jnp lowering (CPU measurement substrate)
-    "pallas"            Pallas kernels; interpret mode off-TPU (CI-safe),
-                        Mosaic-compiled on a real TPU
-    "pallas-interpret"  Pallas kernels, interpret mode forced everywhere
+    "pallas"            Pallas kernels compiled by Mosaic; raises unless
+                        JAX's default backend is a TPU
+    "pallas-interpret"  Pallas kernels in the Pallas interpreter (CPU tests)
 
 Selection flows either explicitly (``backend="pallas"`` through
 ``tune_workload`` / ``TaskScheduler`` / ``DispatchContext`` / the
@@ -95,17 +95,25 @@ def backend_names() -> List[str]:
     return sorted(_BACKENDS)
 
 
+def check_backend_spec(spec: Optional[str] = None) -> str:
+    """Resolve ``spec`` and raise ``KeyError`` if no backend has that
+    name.  Unlike :func:`get_backend` this builds nothing, so a process
+    that only hands the spec to its workers never initializes a device."""
+    spec = resolve_backend_spec(spec)
+    if spec not in _BACKENDS:
+        raise KeyError(
+            f"unknown backend {spec!r}; available: {', '.join(backend_names())}"
+        )
+    return spec
+
+
 def get_backend(spec: Optional[str] = None) -> Backend:
     """Instantiate (memoized) a backend from a registry spec.
 
     ``None`` resolves through ``REPRO_BACKEND``; unknown names raise
     ``KeyError`` listing what is available.
     """
-    spec = resolve_backend_spec(spec)
-    if spec not in _BACKENDS:
-        raise KeyError(
-            f"unknown backend {spec!r}; available: {', '.join(backend_names())}"
-        )
+    spec = check_backend_spec(spec)
     if spec not in _INSTANCES:
         _INSTANCES[spec] = _BACKENDS[spec]()
     return _INSTANCES[spec]
@@ -134,18 +142,24 @@ class PallasBackend(Backend):
     structural lowering so measurement batches never hard-fail on mixed
     task sets (the fallback is recorded in ``Lowered.meta``).
 
-    ``interpret=None`` auto-detects: interpret mode off-TPU (runs in CI
-    on CPU), Mosaic-compiled on TPU.
+    ``interpret=False`` compiles with Mosaic and refuses to exist off a
+    TPU, so a CPU run can never pass itself off as a chip run;
+    ``interpret=True`` runs the kernels in the Pallas interpreter.
     """
 
     name = "pallas"
 
-    def __init__(self, interpret: Optional[bool] = None):
-        if interpret is None:
+    def __init__(self, interpret: bool):
+        if not interpret:
             import jax
 
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = bool(interpret)
+            if jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    "backend 'pallas' compiles with Mosaic and needs a TPU, "
+                    f"but JAX's default backend is {jax.default_backend()!r}; "
+                    "use 'pallas-interpret' for the Pallas interpreter"
+                )
+        self.interpret = interpret
 
     def supports(self, func) -> bool:
         from . import pallas_backend
@@ -174,14 +188,13 @@ class PallasBackend(Backend):
         This is the *untuned* fallback: when the database holds a tuned
         ``attention`` record the dispatch layer serves the fully-lowered
         kernel (db-tuned blocks) and never reaches here.  Blocks snap to
-        the largest divisor of the sequence length <= the MXU-native 128
-        tile — the pre-tuning fixed default.
+        the aligned divisor of the sequence length nearest the MXU-native
+        128 tile — the pre-tuning fixed default.
         """
-        from ..kernels.flash_attention import best_divisor, flash_attention
+        from ..kernels.flash_attention import flash_attention
 
-        bq = best_divisor(int(q.shape[2]), 128)
         return flash_attention(
-            q, k, v, block_q=bq, block_kv=bq, interpret=self.interpret,
+            q, k, v, block_q=128, block_kv=128, interpret=self.interpret,
             **kwargs,
         )
 
@@ -193,7 +206,7 @@ def _make_jnp() -> Backend:
 
 @register_backend("pallas")
 def _make_pallas() -> Backend:
-    return PallasBackend(interpret=None)
+    return PallasBackend(interpret=False)
 
 
 @register_backend("pallas-interpret")

@@ -15,7 +15,10 @@ context is active::
 
 Fallback is transparent: no database record, an invalid stored trace, or
 a shape the context has never seen all return ``None`` from the lookup
-and the layer keeps its jnp reference path.  Lookups happen at *trace
+and the layer keeps its jnp reference path.  A record that exists but
+cannot be lowered, or a mesh-served kernel that fails to build, raises:
+running the reference instead would hide a broken kernel behind a
+passing run.  Lookups happen at *trace
 time* (shapes are static under jit), so a dispatched forward bakes the
 tuned kernels into its jaxpr and pays zero per-call dispatch cost.
 
@@ -37,7 +40,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..backends.registry import get_backend, resolve_backend_spec
@@ -209,7 +212,8 @@ class DispatchContext:
 
     def kernel(self, key: str) -> Optional[CompiledKernel]:
         """Compiled kernel for ``key`` (lazy; None caches the miss, and
-        ``miss_reasons[key]`` records why)."""
+        ``miss_reasons[key]`` records why).  A schedule the backend
+        cannot lower raises."""
         if key in self._compiled:
             return self._compiled[key]
         func = self._funcs.get(key)
@@ -221,26 +225,16 @@ class DispatchContext:
             if sch is None:
                 self.miss_reasons[key] = source
             else:
-                try:
-                    lowered = get_backend(self.backend).lower(
-                        sch, workload_key=key
-                    )
-                except Exception:
-                    # a schedule the backend cannot realize (e.g. a Pallas
-                    # grid cap) is a miss, not a crash: the layer falls
-                    # back to its jnp reference path
-                    lowered = None
-                    self.miss_reasons[key] = "lowering_failed"
-                if lowered is not None:
-                    kern = CompiledKernel(
-                        key=key,
-                        func=func,
-                        fn=jax.jit(lowered.fn),
-                        out_name=func.outputs[0].name,
-                        source=source,
-                        latency_s=lat,
-                        meta=lowered.meta,
-                    )
+                lowered = get_backend(self.backend).lower(sch, workload_key=key)
+                kern = CompiledKernel(
+                    key=key,
+                    func=func,
+                    fn=jax.jit(lowered.fn),
+                    out_name=func.outputs[0].name,
+                    source=source,
+                    latency_s=lat,
+                    meta=lowered.meta,
+                )
         self._compiled[key] = kern
         return kern
 
@@ -341,11 +335,7 @@ class DispatchContext:
             m *= int(s)
         mesh = get_mesh()
         if mesh is not None:
-            try:
-                out = self._mesh_dense(x, w, transpose_w, m, n, k, mesh)
-            except Exception:
-                self._note("fallback", None, "dense", "mesh_error")
-                out = None
+            out = self._mesh_dense(x, w, transpose_w, m, n, k, mesh)
             if out is not None:
                 return out
         kern = self._lookup(workload_key("dense", m=m, n=n, k=k), "dense")
@@ -390,11 +380,7 @@ class DispatchContext:
         N = int(b.shape[-1])
         mesh = get_mesh()
         if mesh is not None:
-            try:
-                out = self._mesh_batch_matmul(a, b, B, M, N, K, bdims, mesh)
-            except Exception:
-                self._note("fallback", None, "batch_matmul", "mesh_error")
-                out = None
+            out = self._mesh_batch_matmul(a, b, B, M, N, K, bdims, mesh)
             if out is not None:
                 return out
         kern = self._lookup(
@@ -476,15 +462,11 @@ class DispatchContext:
         if default_scale and not (window is not None and not causal):
             mesh = get_mesh()
             if mesh is not None:
-                try:
-                    out = self._mesh_attention(
-                        q, k, v, B, H, KVH, S, D,
-                        causal=causal, window=window, softcap=softcap,
-                        ref=ref, mesh=mesh,
-                    )
-                except Exception:
-                    self._note("fallback", None, "attention", "mesh_error")
-                    out = None
+                out = self._mesh_attention(
+                    q, k, v, B, H, KVH, S, D,
+                    causal=causal, window=window, softcap=softcap,
+                    ref=ref, mesh=mesh,
+                )
                 if out is not None:
                     return out
             key = workload_key(
@@ -723,7 +705,7 @@ class DispatchContext:
 
             fwd = shard_map(
                 body, mesh=mesh, in_specs=(x_spec, w_spec),
-                out_specs=o_spec, check_rep=False,
+                out_specs=o_spec, check_vma=False,
             )
 
             def ref(x2, w2):
@@ -763,7 +745,7 @@ class DispatchContext:
 
             fwd = shard_map(
                 body, mesh=mesh, in_specs=(spec, spec),
-                out_specs=spec, check_rep=False,
+                out_specs=spec, check_vma=False,
             )
 
             def ref(a2, b2):
@@ -818,7 +800,7 @@ class DispatchContext:
 
             fwd = shard_map(
                 body, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                out_specs=q_spec, check_rep=False,
+                out_specs=q_spec, check_vma=False,
             )
 
             def ref5(q5, k2, v2):

@@ -24,14 +24,22 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def best_divisor(n: int, target: int) -> int:
-    """Divisor of ``n`` nearest to ``target`` (Pallas needs exact tiling)."""
-    best, bd = 1, abs(target - 1)
+# Mosaic accepts a block whose last dim is a multiple of LANE and whose
+# second-to-last is a multiple of SUBLANE, or either one equal to the
+# array's full extent
+SUBLANE, LANE = 8, 128
+
+
+def best_divisor(n: int, target: int, align: int) -> int:
+    """Divisor of ``n`` nearest to ``target`` among the multiples of
+    ``align`` and ``n`` itself (Pallas needs exact tiling; Mosaic needs
+    the alignment)."""
+    best, bd = n, abs(n - target)
     d = 1
     while d * d <= n:
         if n % d == 0:
             for c in (d, n // d):
-                if abs(c - target) < bd:
+                if c % align == 0 and abs(c - target) < bd:
                     best, bd = c, abs(c - target)
         d += 1
     return best
@@ -104,18 +112,18 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """q: (B, H, S, D); k, v: (B, KVH, S, D); returns (B, H, S, D)."""
     B, H, S, D = q.shape
     KVH = k.shape[1]
     G = H // KVH
     scale = scale if scale is not None else 1.0 / (D**0.5)
-    # snap requested blocks to divisors of S: BlockSpecs need exact tiling,
-    # and tuned (block_q, block_kv) may come from a trace sampled on a
+    # snap requested blocks to aligned divisors of S: BlockSpecs need exact
+    # tiling, and tuned (block_q, block_kv) may come from a trace sampled on a
     # different-shaped relative of this call
-    bq = best_divisor(S, min(block_q, S))
-    bkv = best_divisor(S, min(block_kv, S))
+    bq = best_divisor(S, min(block_q, S), SUBLANE)
+    bkv = best_divisor(S, min(block_kv, S), SUBLANE)
     nq, nkv = S // bq, S // bkv
     kernel = functools.partial(
         _attn_kernel,
@@ -194,9 +202,9 @@ def _decode_kernel(
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    # the mask is pure data: an additive (bkv,) bias row — 0 attendable,
+    # the mask is pure data: an additive (1, bkv) bias row — 0 attendable,
     # -1e30 not — computed by the caller from the per-slot lengths
-    s = s + b_ref[0][None, :]
+    s = s + b_ref[0]
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -224,7 +232,7 @@ def decode_flash_attention(
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Single-token decode attention over a fixed-shape KV cache.
 
@@ -237,7 +245,8 @@ def decode_flash_attention(
     B, KVH, G, D = q.shape
     T = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D**0.5)
-    bkv = best_divisor(T, min(block_kv, T))
+    # bkv is the lane dim of the bias block
+    bkv = best_divisor(T, min(block_kv, T), LANE)
     nkv = T // bkv
     kernel = functools.partial(
         _decode_kernel, nkv=nkv, scale=scale, softcap=softcap
@@ -251,7 +260,7 @@ def decode_flash_attention(
         return (bh, ki, 0)
 
     def bmap(bh, ki):
-        return (bh // KVH, ki)  # bias is per sequence, shared across heads
+        return (bh // KVH, 0, ki)  # bias is per sequence, shared across heads
 
     out = pl.pallas_call(
         kernel,
@@ -260,7 +269,7 @@ def decode_flash_attention(
             pl.BlockSpec((1, G, D), qmap),
             pl.BlockSpec((1, bkv, D), kvmap),
             pl.BlockSpec((1, bkv, D), kvmap),
-            pl.BlockSpec((1, bkv), bmap),
+            pl.BlockSpec((1, 1, bkv), bmap),
         ],
         out_specs=pl.BlockSpec((1, G, D), qmap),
         out_shape=jax.ShapeDtypeStruct((B * KVH, G, D), q.dtype),
@@ -279,7 +288,9 @@ def decode_flash_attention(
         q.reshape(B * KVH, G, D),
         k.reshape(B * KVH, T, D),
         v.reshape(B * KVH, T, D),
-        bias,
+        # (B, 1, T): a (1, bkv) block over the last two dims is legal for
+        # any batch size, where a (1, bkv) block over (B, T) is not
+        bias.reshape(B, 1, T),
     )
     return out.reshape(B, KVH, G, D)
 
@@ -313,9 +324,9 @@ def _paged_decode_kernel(
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    # mask as data, like _decode_kernel: the (ps,) bias row covers both
+    # mask as data, like _decode_kernel: the (1, ps) bias row covers both
     # the per-slot length and any page the slot never wrote
-    s = s + b_ref[0][None, :]
+    s = s + b_ref[0, 0]
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -343,7 +354,7 @@ def paged_decode_flash_attention(
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Single-token decode attention reading straight through a page table.
 
@@ -381,7 +392,7 @@ def paged_decode_flash_attention(
         return (t[bh // KVH, ki], bh % KVH, 0, 0)
 
     def bmap(bh, ki, t):
-        return (bh // KVH, ki)  # bias is per sequence, shared across heads
+        return (bh // KVH, ki, 0, 0)  # per sequence, shared across heads
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -390,7 +401,7 @@ def paged_decode_flash_attention(
             pl.BlockSpec((1, G, D), qmap),
             pl.BlockSpec((1, 1, ps, D), kvmap),
             pl.BlockSpec((1, 1, ps, D), kvmap),
-            pl.BlockSpec((1, ps), bmap),
+            pl.BlockSpec((1, 1, 1, ps), bmap),
         ],
         out_specs=pl.BlockSpec((1, G, D), qmap),
         scratch_shapes=[
@@ -414,6 +425,7 @@ def paged_decode_flash_attention(
         q.reshape(B * KVH, G, D),
         k_pool,
         v_pool,
-        bias,
+        # (B, P, 1, ps): one page's bias row is a full-extent (1, ps) block
+        bias.reshape(B, P, 1, ps),
     )
     return out.reshape(B, KVH, G, D)

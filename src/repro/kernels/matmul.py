@@ -51,12 +51,12 @@ def matmul(
     epilogue: str = "none",
     softcap: float = 30.0,
     block_sizes: Tuple[int, int, int] = DEFAULT_BLOCKS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """y = epilogue(x @ w + bias); x: (M, K), w: (K, N).
 
-    ``interpret=True`` runs the kernel body on CPU (this container);
-    on a real TPU pass ``interpret=False`` for the Mosaic lowering.
+    ``interpret=True`` runs the kernel body in the Pallas interpreter
+    (any platform); ``interpret=False`` is the Mosaic lowering (TPU).
     """
     M, K = x.shape
     K2, N = w.shape
@@ -76,8 +76,10 @@ def matmul(
     ]
     args = [x, w]
     if bias is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, k: (j,)))
-        args.append(bias)
+        # a (1, bn) block of a (1, N) row: Mosaic's layout for a 1-D
+        # operand does not match XLA's
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
+        args.append(bias.reshape(1, N))
         body = kernel
     else:
         body = lambda xr, wr, orf, acc: kernel(xr, wr, None, orf, acc)
@@ -116,7 +118,7 @@ def batch_matmul(
     w: jnp.ndarray,
     *,
     block_sizes: Tuple[int, int, int] = DEFAULT_BLOCKS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """y[b] = x[b] @ w[b]; x: (B, M, K), w: (B, K, N).
 

@@ -27,7 +27,7 @@ def row_softmax(
     x: jnp.ndarray,
     *,
     block_rows: int = DEFAULT_ROW_BLOCK,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Numerically-stable softmax over the last axis of a 2-D array."""
     M, N = x.shape

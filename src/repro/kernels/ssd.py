@@ -64,7 +64,7 @@ def ssd(
     C: jnp.ndarray,
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x: (batch, S, H, P); log_a: (batch, S, H); B, C: (batch, S, N)."""
     batch, S, H, P = x.shape

@@ -24,6 +24,7 @@ from ..training import checkpoint as ckpt
 from ..training.fault_tolerance import StragglerDetector, retry
 from ..training.optimizer import OptConfig, adamw_init
 from ..training.train_loop import make_train_step
+from .runtime import enable_compile_cache
 
 
 def main(argv=None):
@@ -40,6 +41,7 @@ def main(argv=None):
     ap.add_argument("--compress-bits", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

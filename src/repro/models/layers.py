@@ -564,12 +564,12 @@ def _ssd_common(p: Dict, x: jnp.ndarray, cfg):
 
 def ssd_mixer(p: Dict, x: jnp.ndarray, cfg, chunk: int = 64) -> jnp.ndarray:
     """Mamba-2 SSD sequence mixer (training / prefill path)."""
-    from ..kernels import ops as kops
+    from ..kernels import ref as kref
 
     B, S, D = x.shape
     H, P = cfg.ssm_heads, cfg.ssm_head_dim
     xin, z, Bm, Cm, log_a = _ssd_common(p, x, cfg)
-    y = kops.ssd(xin, log_a, Bm, Cm, chunk=min(chunk, S), backend="jnp")
+    y = kref.ssd_chunked(xin, log_a, Bm, Cm, chunk=min(chunk, S))
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     return jnp.einsum("bsi,id->bsd", y.reshape(B, S, H * P), p["wo"])
 

@@ -21,7 +21,10 @@ The parent enforces:
   regardless of which worker finished first.
 
 Workers are spawned (not forked): the parent has a live JAX runtime and
-forking it is unsound.  Worker startup (~seconds for the JAX import) is
+forking it is unsound.  On a TPU host each worker is pinned to a chip of
+its own (:func:`repro.launch.runtime.chip_child_envs`): the pool
+defaults to one worker per chip and refuses more, or a parent that
+already holds the chips.  Worker startup (~seconds for the JAX import) is
 amortized by keeping the pool alive across ``run()`` batches; ``warm()``
 pre-spawns workers so the import overlaps the parent's own search work.
 """
@@ -110,6 +113,11 @@ def _measure_worker(payload: dict) -> dict:
         }
 
 
+def _pin_worker(envs) -> None:
+    """Pool initializer: take one chip's environment before JAX starts."""
+    os.environ.update(envs.get())
+
+
 def _warm_worker(_: int) -> bool:
     """Pre-import the heavy deps so the first real batch finds workers hot."""
     import jax  # noqa: F401
@@ -137,13 +145,17 @@ class ProcessPoolRunner(Runner):
         start_method: str = "spawn",
         backend: Optional[str] = None,
     ):
-        from ...backends.registry import get_backend, resolve_backend_spec
+        from ...backends.registry import check_backend_spec
+        from ...launch.runtime import host_tpu_chips
 
-        self.backend = resolve_backend_spec(backend)
         # validate eagerly: a typo'd spec must raise here, not burn the
-        # whole tuning budget as per-candidate "failures" inside workers
-        get_backend(self.backend)
-        self.max_workers = max_workers or min(max(os.cpu_count() or 2, 2), 8)
+        # whole tuning budget as per-candidate "failures" inside workers.
+        # Only the name: building the backend would claim the device the
+        # workers need.
+        self.backend = check_backend_spec(backend)
+        self.max_workers = max_workers or len(host_tpu_chips()) or min(
+            max(os.cpu_count() or 2, 2), 8
+        )
         self.timeout_s = timeout_s
         self.repeats = repeats
         self.warmup = warmup
@@ -180,10 +192,19 @@ class ProcessPoolRunner(Runner):
 
     def _executor_or_new(self) -> cf.ProcessPoolExecutor:
         if self._executor is None:
+            from ...launch.runtime import chip_child_envs
+
+            envs = chip_child_envs(self.max_workers)
             self._fix_unspawnable_main()
             ctx = mp.get_context(self.start_method)
+            init = {}
+            if any(envs):
+                q = ctx.Queue()
+                for e in envs:
+                    q.put(e)
+                init = {"initializer": _pin_worker, "initargs": (q,)}
             self._executor = cf.ProcessPoolExecutor(
-                max_workers=self.max_workers, mp_context=ctx
+                max_workers=self.max_workers, mp_context=ctx, **init
             )
             self._cold = True
         return self._executor

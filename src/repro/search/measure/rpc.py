@@ -298,7 +298,7 @@ class RPCRunner(Runner):
         backend: Optional[str] = None,
         check: bool = True,
     ):
-        from ...backends.registry import get_backend, resolve_backend_spec
+        from ...backends.registry import check_backend_spec
 
         addrs = parse_addresses(address)
         if not addrs:
@@ -306,8 +306,8 @@ class RPCRunner(Runner):
                 "RPCRunner needs at least one worker address, e.g. "
                 '"rpc://127.0.0.1:7070,127.0.0.1:7071"'
             )
-        self.backend = resolve_backend_spec(backend)
-        get_backend(self.backend)  # fail fast on a typo'd spec
+        # the name only: the workers, not this process, hold the device
+        self.backend = check_backend_spec(backend)
         self.timeout_s = timeout_s
         self.repeats = repeats
         self.warmup = warmup
@@ -722,9 +722,14 @@ def spawn_local_workers(
     (which it does after importing jax and building its inner runner), so
     an ``RPCRunner`` created against the returned addresses connects
     immediately.  Caller owns the processes — ``handle.kill()`` or
-    ``RPCRunner.shutdown_workers()`` to stop them."""
+    ``RPCRunner.shutdown_workers()`` to stop them.  On a TPU host each
+    worker gets a chip of its own, and more workers than chips raise
+    (:func:`repro.launch.runtime.chip_child_envs`)."""
+    from ...launch.runtime import chip_child_envs
+
+    envs = chip_child_envs(n)
     handles: List[WorkerHandle] = []
-    for _ in range(n):
+    for chip_env in envs:
         cmd = [sys.executable, "-m", "repro.search.measure.worker", "--port", "0"]
         if backend:
             cmd += ["--backend", backend]
@@ -738,7 +743,7 @@ def spawn_local_workers(
             stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT,
             text=True,
-            env=dict(os.environ),
+            env={**os.environ, **chip_env},
         )
         deadline = time.monotonic() + startup_timeout_s
         lines: List[str] = []

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import re
 import socket
 import subprocess
@@ -143,7 +144,12 @@ def spawn_serving_workers(
 
     Same idiom as ``repro.search.measure.rpc.spawn_local_workers``: each
     worker is a ``python -m repro.serving.worker`` subprocess on an
-    ephemeral port; a drain thread keeps its stdout from blocking."""
+    ephemeral port; a drain thread keeps its stdout from blocking.  On a
+    TPU host each worker gets a chip of its own, and more workers than
+    chips raise (:func:`repro.launch.runtime.chip_child_envs`)."""
+    from ..launch.runtime import chip_child_envs
+
+    envs = chip_child_envs(n)
     cmd = [
         sys.executable, "-m", "repro.serving.worker",
         "--port", "0", "--model", model,
@@ -157,24 +163,29 @@ def spawn_serving_workers(
         cmd += ["--db", db]
     cmd += list(extra_args)
     links: List[_WorkerLink] = []
+    proc = None
     try:
-        for i in range(n):
+        for i, chip_env in enumerate(envs):
             proc = subprocess.Popen(
                 cmd,
                 stdout=subprocess.PIPE,
                 stderr=subprocess.STDOUT,
                 text=True,
+                env={**os.environ, **chip_env},
             )
             deadline = time.monotonic() + startup_timeout_s
             link = None
+            lines: List[str] = []
             assert proc.stdout is not None
             while time.monotonic() < deadline:
                 line = proc.stdout.readline()
                 if not line:
+                    tail = "\n".join(lines[-20:])
                     raise RuntimeError(
                         f"serving worker {i} exited before READY "
-                        f"(rc={proc.poll()})"
+                        f"(rc={proc.poll()}); output:\n{tail}"
                     )
+                lines.append(line.rstrip())
                 mo = _READY_RE.search(line)
                 if mo:
                     link = _WorkerLink(
@@ -197,6 +208,9 @@ def spawn_serving_workers(
     except Exception:
         for link in links:
             link.kill()
+        if proc is not None and proc.poll() is None:
+            proc.kill()  # the worker that failed to come up
+            proc.wait()
         raise
     return links
 

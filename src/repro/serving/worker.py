@@ -261,6 +261,9 @@ def main(argv: Optional[List[str]] = None) -> None:
         "--once", action="store_true", help="exit after the first client leaves"
     )
     args = ap.parse_args(argv)
+    from ..launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
     scheduler = build_scheduler(
         args.model,
         max_slots=args.max_slots,

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_analysis import HloModule, analyze_hlo
+from repro.launch.mesh import make_mesh
 
 
 class TestHloTripCounts:
@@ -157,7 +158,7 @@ class TestAdaptiveShardingPolicy:
     def test_policy_matrix(self):
         from repro.distributed import sharding as shd
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with shd.use_mesh(mesh):
             x = jnp.zeros((2, 8, 16, 64))
             # model axis size 1 -> everything divides -> constraint applies
@@ -170,7 +171,7 @@ class TestAdaptiveShardingPolicy:
         prev = dict(shd.STRATEGY)
         try:
             shd.set_strategy(constrain_attn_acts="auto")
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
+            mesh = make_mesh((1, 1), ("data", "model"))
             # emulate the decision logic directly
             assert shd.STRATEGY["constrain_attn_acts"] == "auto"
         finally:
@@ -186,9 +187,18 @@ class TestAdaptiveShardingPolicy:
 
 class TestPallasBackendExtraction:
     def test_divisor_snap(self):
-        from repro.backends.pallas_backend import _best_divisor
+        from repro.backends.pallas_backend import MATMUL_ALIGNS, snap_blocks
+        from repro.kernels.flash_attention import best_divisor
 
-        assert _best_divisor(128, 100) == 128
-        assert _best_divisor(96, 100) == 96
-        assert _best_divisor(100, 3) in (2, 4)  # both at distance 1
-        assert _best_divisor(7, 100) == 7
+        assert best_divisor(128, 100, 1) == 128
+        assert best_divisor(96, 100, 1) == 96
+        assert best_divisor(100, 3, 1) in (2, 4)  # both at distance 1
+        assert best_divisor(7, 100, 1) == 7
+        # Mosaic's rule: 8-aligned rows, 128-aligned lanes, or the full
+        # dim.  d_model=576 has no 128-aligned divisor but itself.
+        assert snap_blocks((128, 576, 576), (128, 128, 128), MATMUL_ALIGNS) == (
+            128, 576, 576
+        )
+        assert snap_blocks((24, 1536, 1536), (4, 100, 300), MATMUL_ALIGNS) == (
+            8, 128, 256
+        )

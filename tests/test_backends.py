@@ -56,9 +56,21 @@ class TestRegistry:
     def test_env_default(self, monkeypatch):
         monkeypatch.delenv("REPRO_BACKEND", raising=False)
         assert default_backend_spec() == "jnp"
-        monkeypatch.setenv("REPRO_BACKEND", "pallas")
-        assert default_backend_spec() == "pallas"
-        assert get_backend(None).name == "pallas"
+        monkeypatch.setenv("REPRO_BACKEND", "pallas-interpret")
+        assert default_backend_spec() == "pallas-interpret"
+        assert get_backend(None).name == "pallas-interpret"
+
+    def test_pallas_refuses_to_run_off_tpu(self):
+        # "pallas" means Mosaic: off a TPU it raises instead of quietly
+        # running the interpreter, and no instance is memoized
+        assert jax.default_backend() != "tpu"
+        with pytest.raises(RuntimeError, match="pallas-interpret"):
+            get_backend("pallas")
+        from repro.integration.dispatch import DispatchContext
+
+        with pytest.raises(RuntimeError, match="TPU"):
+            DispatchContext(Database(None), tasks=[], backend="pallas")
+        assert get_backend("pallas-interpret").interpret is True
 
     def test_register_plugin(self):
         @register_backend("test-dummy")

@@ -61,12 +61,13 @@ class TestMultiDeviceLowering:
             import jax.numpy as jnp
             from repro.configs.base import get_config, ShapeConfig
             from repro.distributed import sharding as shd
+            from repro.launch.mesh import make_mesh
             from repro.models.registry import (
                 build_model, train_batch_specs, decode_input_specs)
             from repro.training.optimizer import OptConfig, adamw_init
             from repro.training.train_loop import make_train_step
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             cfg = get_config("smollm-135m", smoke=True)
             model = build_model(cfg)
             pspecs = model.param_specs()

@@ -25,7 +25,7 @@ class TestMatmulKernel:
     def test_block_shape_sweep(self, M, N, K, bm, bn, bk):
         x = RNG.standard_normal((M, K), dtype=np.float32)
         w = RNG.standard_normal((K, N), dtype=np.float32)
-        got = matmul(x, w, block_sizes=(bm, bn, bk))
+        got = matmul(x, w, block_sizes=(bm, bn, bk), interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref.matmul(x, w)), rtol=2e-4, atol=2e-4
         )
@@ -37,7 +37,9 @@ class TestMatmulKernel:
         x = RNG.standard_normal((64, 32), dtype=np.float32)
         w = RNG.standard_normal((32, 64), dtype=np.float32)
         b = RNG.standard_normal((64,), dtype=np.float32) if "bias" in ep else None
-        got = matmul(x, w, b, epilogue=ep, block_sizes=(32, 32, 32))
+        got = matmul(
+            x, w, b, epilogue=ep, block_sizes=(32, 32, 32), interpret=True
+        )
         want = ref.matmul(x, w, b, ep)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
@@ -50,7 +52,7 @@ class TestMatmulKernel:
         dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
         x = jnp.asarray(RNG.standard_normal((64, 64)), dtype=dt)
         w = jnp.asarray(RNG.standard_normal((64, 64)), dtype=dt)
-        got = matmul(x, w, block_sizes=(32, 32, 32))
+        got = matmul(x, w, block_sizes=(32, 32, 32), interpret=True)
         want = ref.matmul(x, w)
         np.testing.assert_allclose(
             np.asarray(got, np.float32),
@@ -77,7 +79,7 @@ class TestFlashAttentionKernel:
         v = RNG.standard_normal((B, KVH, S, D), dtype=np.float32)
         got = flash_attention(
             q, k, v, causal=causal, window=win, softcap=cap,
-            block_q=bq, block_kv=bkv,
+            block_q=bq, block_kv=bkv, interpret=True,
         )
         want = ref.flash_attention(q, k, v, causal=causal, window=win, softcap=cap)
         np.testing.assert_allclose(
@@ -101,7 +103,7 @@ class TestSSDKernel:
         Bm = RNG.standard_normal((B, S, N), dtype=np.float32) * 0.3
         Cm = RNG.standard_normal((B, S, N), dtype=np.float32) * 0.3
         want = ref.ssd_scan(x, la, Bm, Cm)
-        got = ssd(x, la, Bm, Cm, chunk=chunk)
+        got = ssd(x, la, Bm, Cm, chunk=chunk, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=3e-3, atol=3e-3
         )
@@ -141,7 +143,7 @@ class TestSSDKernel:
 class TestTraceToPallas:
     def test_tuned_trace_lowers_to_pallas_kernel(self):
         """MetaSchedule trace -> BlockSpec extraction -> Pallas matmul."""
-        from repro.backends.pallas_backend import lower_dense_to_pallas
+        from repro.backends.pallas_backend import lower_dense
         from repro.core.modules import SpaceGenerator, default_modules
         from repro.core.tir import random_inputs
         from repro.core.validator import validate_trace
@@ -155,7 +157,7 @@ class TestTraceToPallas:
             res = validate_trace(f, sch.trace)
             if not res.ok:
                 continue
-            fn, blocks = lower_dense_to_pallas(res.schedule)
+            fn, _ = lower_dense(res.schedule, interpret=True)
             ins = random_inputs(f, 1)
             out = fn(ins)
             want = ref.matmul(ins["X"], ins["W"], ins["bias"], "bias_relu")

@@ -258,7 +258,7 @@ class TestPagedEquivalence:
             chunk=CHUNK, paged=True, page_size=PAGE,
         )
         ctx = DispatchContext(
-            None, tasks=tasks, mode="default", backend="pallas"
+            None, tasks=tasks, mode="default", backend="pallas-interpret"
         )
         prompts = _prompts(cfg, [4, 6])
         budgets = [3, 2]

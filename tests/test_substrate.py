@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs.base import ShapeConfig, get_config
 from repro.data.pipeline import SyntheticTokenPipeline
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.training import checkpoint as ckpt
 from repro.training.fault_tolerance import StepFailure, StragglerDetector, retry
@@ -106,7 +107,7 @@ class TestCheckpoint:
 
         tree = {"w": jnp.arange(8.0)}
         ckpt.save(str(tmp_path), 1, tree)
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         sh = {"w": NamedSharding(mesh, P("model"))}
         _, back, _ = ckpt.restore(str(tmp_path), mesh=mesh, shardings=sh)
         np.testing.assert_array_equal(np.asarray(back["w"]), np.arange(8.0))
@@ -170,11 +171,11 @@ class TestFaultTolerance:
 
 class TestShardingRules:
     def _mesh(self):
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1), ("data", "model"))
 
     def test_param_divisibility_fallback(self):
         """smollm's 9 heads can't shard 16-way -> falls back, never errors."""
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = shd.spec_for_param(mesh, (576, 576), ("embed", "heads"))
         assert len(spec) == 2
 
