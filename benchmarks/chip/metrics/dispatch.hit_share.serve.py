@@ -1,0 +1,8 @@
+"""Dispatch lookups of the serve program served by a tuned kernel, in
+percent of all lookups (hits, misses and fallbacks, stats_by_key())."""
+
+import harness
+
+
+def read(obs):
+    return harness.for_job(obs, "serve", harness.hit_share)
