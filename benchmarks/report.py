@@ -2,9 +2,11 @@
 
 Reads one or more trace files produced by running with ``REPRO_TRACE``
 set (see :mod:`repro.obs.trace`), folds them with
-:func:`repro.obs.report.fold`, prints the human-readable rendering, and
-writes the machine-readable ``BENCH_tuning_report.json`` consumed by the
-CI gate (``check_regression.py --report ... --min-dispatch-hit-rate``).
+:func:`repro.obs.report.fold`, prints the human-readable rendering (the
+tuning wall-clock split into build, compile, timing and search
+overhead, cost-model correlation, dispatch coverage, ...), and writes
+the machine-readable ``BENCH_tuning_report.json`` consumed by the CI gate
+(``check_regression.py --report ... --min-dispatch-hit-rate``).
 
 Usage::
 

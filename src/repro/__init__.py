@@ -41,8 +41,6 @@ _EXPORTS = {
     "create_runner": "search.measure",
     "as_runner": "search.measure",
     "runner_names": "search.measure",
-    # telemetry
-    "metrics": "obs",
 }
 
 __all__ = sorted(_EXPORTS)
@@ -67,7 +65,6 @@ def __dir__():
 if TYPE_CHECKING:  # static-analysis view of the lazy exports
     from .integration.dispatch import DispatchContext  # noqa: F401
     from .integration.extract import extract_tasks  # noqa: F401
-    from .obs import metrics  # noqa: F401
     from .search.database import Database  # noqa: F401
     from .search.evolutionary import SearchConfig  # noqa: F401
     from .search.measure import (  # noqa: F401

@@ -145,7 +145,7 @@ def _check_grid(steps: int, blocks) -> None:
 
 
 def lower_dense(
-    sch: Schedule, *, interpret: bool
+    sch: Schedule, *, interpret: bool, task: str = ""
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned dense (+fused epilogue) via the Pallas matmul kernel."""
     from ..kernels import matmul as mm
@@ -172,6 +172,7 @@ def lower_dense(
             epilogue=epilogue,
             block_sizes=blocks,
             interpret=interpret,
+            task=task,
         )
         return {func.outputs[0].name: out}
 
@@ -179,7 +180,7 @@ def lower_dense(
 
 
 def lower_batch_matmul(
-    sch: Schedule, *, interpret: bool
+    sch: Schedule, *, interpret: bool, task: str = ""
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned batched matmul via the Pallas bmm kernel (batch grid dim)."""
     from ..kernels import matmul as mm
@@ -197,7 +198,8 @@ def lower_batch_matmul(
 
     def fn(inputs: Dict):
         out = mm.batch_matmul(
-            inputs["A"], inputs["B"], block_sizes=blocks, interpret=interpret
+            inputs["A"], inputs["B"], block_sizes=blocks, interpret=interpret,
+            task=task,
         )
         return {func.outputs[0].name: out}
 
@@ -205,7 +207,7 @@ def lower_batch_matmul(
 
 
 def lower_sfm(
-    sch: Schedule, *, interpret: bool
+    sch: Schedule, *, interpret: bool, task: str = ""
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned row softmax via the Pallas online-softmax kernel."""
     from ..kernels import softmax as sm
@@ -221,7 +223,9 @@ def lower_sfm(
     }
 
     def fn(inputs: Dict):
-        out = sm.row_softmax(inputs["A"], block_rows=bm, interpret=interpret)
+        out = sm.row_softmax(
+            inputs["A"], block_rows=bm, interpret=interpret, task=task
+        )
         return {func.outputs[0].name: out}
 
     return fn, meta
@@ -256,7 +260,7 @@ def extract_attention_blocks(sch: Schedule) -> Optional[Tuple[int, int]]:
 
 
 def lower_attention(
-    sch: Schedule, *, interpret: bool
+    sch: Schedule, *, interpret: bool, task: str = ""
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned fused attention via the Pallas flash kernel.
 
@@ -291,6 +295,7 @@ def lower_attention(
             block_q=bq,
             block_kv=bkv,
             interpret=interpret,
+            task=task,
         )
         return {func.outputs[0].name: out.reshape(b, kvh, g, s, d)}
 
@@ -311,7 +316,7 @@ def extract_decode_kv_block(sch: Schedule) -> Optional[int]:
 
 
 def lower_attention_decode(
-    sch: Schedule, *, interpret: bool
+    sch: Schedule, *, interpret: bool, task: str = ""
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Tuned single-token decode attention via the Pallas decode kernel.
 
@@ -352,6 +357,7 @@ def lower_attention_decode(
             softcap=softcap,
             block_kv=bkv,
             interpret=interpret,
+            task=task,
         )
         return {func.outputs[0].name: out}
 
@@ -373,26 +379,30 @@ def _block_meta(kernel: str, sampled, snapped) -> Dict[str, Any]:
 
 
 def lower_to_pallas(
-    sch: Schedule, *, interpret: bool
+    sch: Schedule, *, interpret: bool, task: str = ""
 ) -> Tuple[Callable, Dict[str, Any]]:
     """Dispatch a supported schedule to its Pallas lowering.
 
     Returns ``(fn, meta)`` where ``fn`` is ``callable(dict) -> dict`` and
     ``meta`` records the kernel used plus sampled/snapped tile provenance.
-    Raises ``ValueError`` for unsupported workloads (check ``supports``).
+    ``task`` (the workload key) goes into the kernel's metadata, so the
+    candidate the search timed and the kernel dispatch serves carry the
+    same identity in a profiler trace.  Raises ``ValueError`` for
+    unsupported workloads (check ``supports``).
     """
     name = sch.func.name
+    kw = dict(interpret=interpret, task=task)
     if name.startswith("dense_"):
-        return lower_dense(sch, interpret=interpret)
+        return lower_dense(sch, **kw)
     if name.startswith("attention_decode"):
         # must route before the generic attention_ prefix: the prefill
         # flash lowering assumes a 5-D square-sequence Q
-        return lower_attention_decode(sch, interpret=interpret)
+        return lower_attention_decode(sch, **kw)
     if name.startswith("attention_"):
-        return lower_attention(sch, interpret=interpret)
+        return lower_attention(sch, **kw)
     if name == "batch_matmul":
-        return lower_batch_matmul(sch, interpret=interpret)
+        return lower_batch_matmul(sch, **kw)
     if name == "sfm":
-        return lower_sfm(sch, interpret=interpret)
+        return lower_sfm(sch, **kw)
     raise ValueError(f"no Pallas lowering for workload {name!r}")
 

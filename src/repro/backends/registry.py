@@ -171,7 +171,7 @@ class PallasBackend(Backend):
 
         if pallas_backend.supports(sch.func):
             fn, meta = pallas_backend.lower_to_pallas(
-                sch, interpret=self.interpret
+                sch, interpret=self.interpret, task=workload_key
             )
             return Lowered(fn, {"backend": self.name, **meta})
         lowered = jnp_backend.build(sch)

@@ -48,7 +48,7 @@ from ..core.modules import SpaceGenerator, default_modules
 from ..core.tir import PrimFunc
 from ..core.validator import first_valid_schedule, validate_trace
 from ..distributed.sharding import get_mesh, shard_workload
-from ..obs import emit, metrics, trace_enabled
+from ..obs import emit, trace_enabled
 from ..search.database import Database, parse_workload_key, workload_key
 
 # active-context stack; layers read the top via current().  Thread-local so
@@ -255,9 +255,9 @@ class DispatchContext:
         reason: Optional[str] = None,
     ) -> None:
         """Record a dispatch outcome ("hit" | "miss" | "fallback") in the
-        per-key table, the metrics registry, and the trace stream.  The
-        legacy ``stats``/``hits_by_key`` counters are NOT touched here —
-        callers keep incrementing those at the historical points."""
+        per-key table and the trace stream.  The legacy
+        ``stats``/``hits_by_key`` counters are NOT touched here — callers
+        keep incrementing those at the historical points."""
         row_key = key if key else f"site:{site}"
         row = self._by_key.get(row_key)
         if row is None:
@@ -272,12 +272,6 @@ class DispatchContext:
             "misses" if outcome == "miss" else "fallbacks"] += 1
         if reason:
             row["reasons"][reason] = row["reasons"].get(reason, 0) + 1
-        metrics().inc(
-            f"dispatch.{outcome}",
-            site=site,
-            mode=self.mode,
-            backend=self.backend,
-        )
         if trace_enabled():
             emit(
                 f"dispatch.{outcome}",

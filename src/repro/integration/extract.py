@@ -33,7 +33,7 @@ import jax
 
 from ..configs.base import ModelConfig, ShapeConfig
 from ..core.workloads import get_workload
-from ..obs import emit, metrics, trace_enabled
+from ..obs import emit, trace_enabled
 from ..search.database import workload_key
 from ..search.measure.hashing import primfunc_structural_hash
 from ..search.task_scheduler import TuneTask
@@ -53,9 +53,8 @@ DECODE_EXTRACTABLE_OPS = (
 
 def _skip(site: str, reason: str) -> None:
     """Dropped-site telemetry: every site the extractor cannot express is
-    dispatch coverage lost, so it must be visible (metrics counter always,
-    ``extract.skip`` trace event when tracing) instead of silent."""
-    metrics().inc("extract.skip", site=site, reason=reason)
+    dispatch coverage lost, so it must be visible (an ``extract.skip``
+    trace event) instead of silent."""
     if trace_enabled():
         emit("extract.skip", site=site, reason=reason)
 
@@ -470,7 +469,6 @@ def shard_sites(sites: Iterable[TaskSite], mesh) -> List[TaskSite]:
         if sw is None or sw.kwargs == s.kwargs:
             out.append(s)
             continue
-        metrics().inc("extract.shard", op=s.op)
         if trace_enabled():
             emit(
                 "extract.shard",

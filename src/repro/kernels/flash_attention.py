@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_metadata
+
 NEG_INF = -1e30
 
 
@@ -113,6 +115,7 @@ def flash_attention(
     block_q: int = 128,
     block_kv: int = 128,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """q: (B, H, S, D); k, v: (B, KVH, S, D); returns (B, H, S, D)."""
     B, H, S, D = q.shape
@@ -167,6 +170,8 @@ def flash_attention(
         if not interpret
         else None,
         interpret=interpret,
+        name="flash_attention",
+        metadata=kernel_metadata(task, (bq, bkv), q.dtype),
     )(
         q.reshape(B * H, S, D),
         k.reshape(B * KVH, S, D),
@@ -233,6 +238,7 @@ def decode_flash_attention(
     scale: Optional[float] = None,
     block_kv: int = 128,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """Single-token decode attention over a fixed-shape KV cache.
 
@@ -284,6 +290,8 @@ def decode_flash_attention(
         if not interpret
         else None,
         interpret=interpret,
+        name="flash_decode",
+        metadata=kernel_metadata(task, (bkv,), q.dtype),
     )(
         q.reshape(B * KVH, G, D),
         k.reshape(B * KVH, T, D),
@@ -355,6 +363,7 @@ def paged_decode_flash_attention(
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """Single-token decode attention reading straight through a page table.
 
@@ -420,6 +429,8 @@ def paged_decode_flash_attention(
         if not interpret
         else None,
         interpret=interpret,
+        name="paged_decode",
+        metadata=kernel_metadata(task, (ps,), q.dtype),
     )(
         table,
         q.reshape(B * KVH, G, D),

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_metadata
 from .ref import apply_epilogue
 
 DEFAULT_BLOCKS = (128, 128, 128)  # MXU-native tiles
@@ -52,11 +53,14 @@ def matmul(
     softcap: float = 30.0,
     block_sizes: Tuple[int, int, int] = DEFAULT_BLOCKS,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """y = epilogue(x @ w + bias); x: (M, K), w: (K, N).
 
     ``interpret=True`` runs the kernel body in the Pallas interpreter
     (any platform); ``interpret=False`` is the Mosaic lowering (TPU).
+    ``task`` is the workload key of the tuned record the blocks came
+    from, carried in the kernel's metadata (:func:`kernel_metadata`).
     """
     M, K = x.shape
     K2, N = w.shape
@@ -96,6 +100,8 @@ def matmul(
         if not interpret
         else None,
         interpret=interpret,
+        name="dense",
+        metadata=kernel_metadata(task, (bm, bn, bk), x.dtype),
     )(*args)
 
 
@@ -119,6 +125,7 @@ def batch_matmul(
     *,
     block_sizes: Tuple[int, int, int] = DEFAULT_BLOCKS,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """y[b] = x[b] @ w[b]; x: (B, M, K), w: (B, K, N).
 
@@ -152,4 +159,6 @@ def batch_matmul(
         if not interpret
         else None,
         interpret=interpret,
+        name="batch_matmul",
+        metadata=kernel_metadata(task, (bm, bn, bk), x.dtype),
     )(x, w)

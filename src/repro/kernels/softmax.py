@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_metadata
+
 DEFAULT_ROW_BLOCK = 128
 
 
@@ -28,6 +30,7 @@ def row_softmax(
     *,
     block_rows: int = DEFAULT_ROW_BLOCK,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """Numerically-stable softmax over the last axis of a 2-D array."""
     M, N = x.shape
@@ -40,4 +43,6 @@ def row_softmax(
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
+        name="row_softmax",
+        metadata=kernel_metadata(task, (bm,), x.dtype),
     )(x)

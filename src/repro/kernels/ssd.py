@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_metadata
+
 
 def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, h_ref, *, chunk: int):
     ci = pl.program_id(1)
@@ -65,6 +67,7 @@ def ssd(
     *,
     chunk: int = 64,
     interpret: bool,
+    task: str = "",
 ) -> jnp.ndarray:
     """x: (batch, S, H, P); log_a: (batch, S, H); B, C: (batch, S, N)."""
     batch, S, H, P = x.shape
@@ -104,5 +107,7 @@ def ssd(
         if not interpret
         else None,
         interpret=interpret,
+        name="ssd",
+        metadata=kernel_metadata(task, (chunk,), x.dtype),
     )(xb, lab, B, C)
     return y.reshape(batch, H, S, P).transpose(0, 2, 1, 3)

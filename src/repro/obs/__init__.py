@@ -1,19 +1,14 @@
-"""Observability: process-wide metrics registry + structured event tracer.
+"""Observability: the structured event tracer.
 
 The instrumented stack (search, measure, dispatch, serving) imports from
-this package only — ``from ..obs import emit, span, metrics`` — so the
-whole layer can be reasoned about (and disabled) in one place.  Tracing
-is off unless ``REPRO_TRACE`` is set (see :mod:`repro.obs.trace`);
-metrics are always on (dict updates, no I/O).
+this package only — ``from ..obs import emit, span`` — so the whole
+layer can be reasoned about (and disabled) in one place.  Tracing is off
+unless ``REPRO_TRACE`` is set or a tracer is installed (see
+:mod:`repro.obs.trace`); while it is on, every span is also a
+``repro.<ev>`` annotation in a running JAX profiler trace.
 """
 
-from .metrics import (  # noqa: F401
-    MetricsRegistry,
-    metrics,
-    quantile,
-    reset_metrics,
-    spearman,
-)
+from .metrics import spearman  # noqa: F401
 from .trace import (  # noqa: F401
     ConsoleSink,
     JsonlSink,
@@ -31,10 +26,6 @@ from .trace import (  # noqa: F401
 )
 
 __all__ = [
-    "MetricsRegistry",
-    "metrics",
-    "reset_metrics",
-    "quantile",
     "spearman",
     "ConsoleSink",
     "JsonlSink",

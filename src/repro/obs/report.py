@@ -3,11 +3,13 @@ diagnostics report.
 
 The report answers the questions the search loop itself cannot:
 
-* **Where did tuning wall-clock go?**  build vs run vs search overhead,
-  computed against the ``tune.session`` span(s) so the three buckets
-  account for the whole session by construction (overhead is the
-  remainder; with parallel runners build+run sums can legitimately
-  exceed wall-clock — the report says so instead of hiding it).
+* **Where did tuning wall-clock go?**  build (validate + lower) vs
+  compile (each candidate's first call: trace, compile or cache load)
+  vs timing (warm-up and timed repeats) vs search overhead, computed
+  against the ``tune.session`` span(s) so the four buckets account for
+  the whole session by construction (overhead is the remainder; with
+  parallel runners the measured buckets can legitimately exceed
+  wall-clock — the report says so instead of hiding it).
 * **Is the cost model learning?**  per-round Spearman rank correlation
   between predicted scores and measured latencies (``costmodel.round``).
 * **What actually got served?**  per-workload-key dispatch
@@ -62,7 +64,7 @@ def fold(events: List[Dict[str, Any]], top_n: int = 10) -> Dict[str, Any]:
     for e in events:
         by_type.setdefault(e["ev"], []).append(e)
 
-    # -- wall clock and the build/run/overhead breakdown ---------------------
+    # -- wall clock and the build/compile/timing/overhead breakdown ----------
     wins = _session_windows(events)
     if wins:
         wall = sum(hi - lo for lo, hi in wins)
@@ -76,9 +78,11 @@ def fold(events: List[Dict[str, Any]], top_n: int = 10) -> Dict[str, Any]:
     builds = [e for e in by_type.get("measure.build", []) if in_tuning(e)]
     runs = [e for e in by_type.get("measure.run", []) if in_tuning(e)]
     build_s = sum(float(e.get("dur_s", 0.0)) for e in builds)
-    run_s = sum(float(e.get("dur_s", 0.0)) for e in runs)
-    overhead_s = max(0.0, wall - build_s - run_s)
-    accounted = (build_s + run_s + overhead_s) / wall if wall > 0 else 1.0
+    compile_s = sum(float(e.get("compile_s", 0.0)) for e in runs)
+    timing_s = sum(float(e.get("timing_s", 0.0)) for e in runs)
+    measured_s = build_s + compile_s + timing_s
+    overhead_s = max(0.0, wall - measured_s)
+    accounted = (measured_s + overhead_s) / wall if wall > 0 else 1.0
 
     # -- per-task round/latency table ----------------------------------------
     tasks: Dict[str, Dict[str, Any]] = {}
@@ -324,7 +328,8 @@ def fold(events: List[Dict[str, Any]], top_n: int = 10) -> Dict[str, Any]:
         "wall_s": round(wall, 4),
         "time_breakdown": {
             "build_s": round(build_s, 4),
-            "run_s": round(run_s, 4),
+            "compile_s": round(compile_s, 4),
+            "timing_s": round(timing_s, 4),
             "search_overhead_s": round(overhead_s, 4),
             "accounted_frac": round(accounted, 4),
         },
@@ -356,13 +361,14 @@ def render_text(report: Dict[str, Any]) -> str:
         f"rounds: {report['rounds']}")
     add("")
     add("-- time breakdown (vs tuning wall-clock) --")
-    add(f"  build            {tb['build_s']:9.2f}s  {_pct(tb['build_s'], wall)}")
-    add(f"  run              {tb['run_s']:9.2f}s  {_pct(tb['run_s'], wall)}")
-    add(f"  search overhead  {tb['search_overhead_s']:9.2f}s  "
-        f"{_pct(tb['search_overhead_s'], wall)}")
+    for label, key in (("build", "build_s"), ("compile", "compile_s"),
+                       ("timing", "timing_s"),
+                       ("search overhead", "search_overhead_s")):
+        add(f"  {label:<16} {tb[key]:9.2f}s  {_pct(tb[key], wall)}")
+    measured = tb["build_s"] + tb["compile_s"] + tb["timing_s"]
     add(f"  accounted: {100.0 * tb['accounted_frac']:.1f}%"
-        + ("  (build+run exceed wall-clock: parallel measurement)"
-           if tb["build_s"] + tb["run_s"] > wall > 0 else ""))
+        + ("  (build+compile+timing exceed wall-clock: parallel measurement)"
+           if measured > wall > 0 else ""))
     add("")
     if report["tasks"]:
         add("-- tasks --")

@@ -5,6 +5,11 @@ Instrumented code calls :func:`emit` (point event) or :func:`span`
 the default — both are a single ``is None`` check, so the hot paths in
 the search/measure/dispatch/serving stack pay nothing.
 
+While a tracer is installed, every span is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<ev>``, entered and left
+with the span: a JAX profiler trace taken meanwhile holds the program's
+spans on the device trace's clock, nested as they nest in the sink.
+
 Event schema (one JSON object per line in a JSONL sink)::
 
     {"ev": "measure.run",        # event type
@@ -134,6 +139,17 @@ def _json_default(x: Any) -> Any:
 # -- tracer ------------------------------------------------------------------
 
 
+def _annotation(ev: str):
+    """A ``repro.<ev>`` profiler annotation, None without JAX.  Imported
+    here, on the tracing-on path only, so this module imports without
+    JAX."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation(f"repro.{ev}")
+
+
 class Tracer:
     def __init__(self, sinks: List[Sink]):
         self.sinks = list(sinks)
@@ -198,9 +214,10 @@ class Tracer:
 
 class _Span:
     """Context manager: emits one event at exit with ``dur_s`` and links
-    children emitted inside to it via the thread-local span stack."""
+    children emitted inside to it via the thread-local span stack; holds
+    a ``repro.<ev>`` profiler annotation open meanwhile."""
 
-    __slots__ = ("tracer", "ev", "fields", "id", "parent", "t0")
+    __slots__ = ("tracer", "ev", "fields", "id", "parent", "t0", "annotation")
 
     def __init__(self, tracer: Tracer, ev: str, fields: Dict[str, Any]):
         self.tracer = tracer
@@ -209,6 +226,7 @@ class _Span:
         self.id = 0
         self.parent = 0
         self.t0 = 0.0
+        self.annotation = None
 
     def note(self, **fields) -> None:
         """Attach fields known only at the end (results, counts...)."""
@@ -219,11 +237,16 @@ class _Span:
         stack = self.tracer._stack()
         self.parent = stack[-1] if stack else 0
         stack.append(self.id)
+        self.annotation = _annotation(self.ev)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, *exc) -> None:
         dur = time.monotonic() - self.t0
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         stack = self.tracer._stack()
         if stack and stack[-1] == self.id:
             stack.pop()

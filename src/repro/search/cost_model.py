@@ -30,7 +30,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..obs import emit, metrics, trace_enabled
+from ..obs import emit, trace_enabled
 
 #: Version stamp written into persisted cost-model files; bump when the
 #: JSON schema documented in docs/db_format.md changes incompatibly.
@@ -205,7 +205,6 @@ class GBDTCostModel:
         Xp, yp = self._pooled()
         self._fit(Xp, yp)
         dt = time.perf_counter() - t0
-        metrics().observe("costmodel.fit_s", dt)
         if trace_enabled():
             emit(
                 "costmodel.update",

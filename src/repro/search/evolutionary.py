@@ -27,7 +27,7 @@ from ..core.schedule import Schedule
 from ..core.tir import PrimFunc
 from ..core.trace import Trace
 from ..core.validator import validate_trace
-from ..obs import ConsoleSink, emit, metrics, span, spearman, trace_enabled
+from ..obs import ConsoleSink, emit, span, spearman, trace_enabled
 from .cost_model import GBDTCostModel
 from .database import Database, TuningRecord
 from .distributions import QUALITY_GAMMA, DecisionDistributions
@@ -256,7 +256,6 @@ class EvolutionarySearch:
                 "kept": len(kept),
             }
             self.prune_events.append(rec)
-            metrics().inc("costmodel.pruned", len(pool) - len(kept), task=self.key)
             if trace_enabled():
                 emit(
                     "costmodel.prune",
@@ -407,20 +406,12 @@ class EvolutionarySearch:
             "trained": model_trained,
         }
         self.round_correlations.append(rec)
-        if rho is not None and model_trained:
-            metrics().observe("costmodel.rank_corr", rho, task=self.key)
         if trace_enabled():
             emit(
                 "costmodel.round",
                 task=self.key,
                 pairs=[[round(p, 6), l] for p, l in pairs],
                 **rec,
-            )
-        metrics().inc("search.measured", len(cands), task=self.key)
-        metrics().inc("search.failures", round_failures, task=self.key)
-        if np.isfinite(self.best_latency):
-            metrics().gauge(
-                "search.best_latency_s", self.best_latency, task=self.key
             )
         # retrain the model on normalized throughput scores: this task's
         # sample pool is replaced wholesale; a model shared across tasks

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List
 
-from ...obs import emit, metrics, trace_enabled
+from ...obs import emit, trace_enabled
 from .hashing import structural_hash
 from .protocol import MeasureInput, MeasureResult, Runner
 
@@ -40,9 +40,6 @@ class CachedRunner(Runner):
         return structural_hash(f"{self.backend}::{mi.workload_key}", mi.trace)
 
     def _note(self, hit: bool, key: str, h: str) -> None:
-        metrics().inc(
-            "cache.hits" if hit else "cache.misses", backend=self.backend
-        )
         if trace_enabled():
             emit(
                 "cache.hit" if hit else "cache.miss",

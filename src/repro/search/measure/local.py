@@ -2,7 +2,10 @@
 
 ``LocalBuilder`` lowers each candidate through the selected lowering
 backend (``backend=`` registry spec, default the ambient
-``REPRO_BACKEND``) and jits it; ``LocalRunner`` times the artifacts.  The
+``REPRO_BACKEND``) and wraps it in ``jax.jit``, which is lazy: nothing
+compiles at build time.  ``LocalRunner`` times the artifacts; the first
+call compiles (or loads from the persistent cache) and is timed apart
+from the warm-up and timed repeats.  The
 split matters even locally: the builder's output is reusable (e.g. for
 correctness checks) and the timing loop is identical for every in-process
 runner.  Process-parallel measurement lives in :mod:`pool`.
@@ -19,13 +22,14 @@ import numpy as np
 from ...backends.registry import get_backend, resolve_backend_spec
 from ...core.tir import PrimFunc, random_inputs
 from ...core.validator import validate_trace
-from ...obs import emit, metrics, trace_enabled
+from ...obs import emit, span, trace_enabled
 from .hashing import structural_hash
 from .protocol import Builder, BuildResult, MeasureInput, MeasureResult, Runner
 
 
 class LocalBuilder(Builder):
-    """Lower + jit each candidate in the current process."""
+    """Validate, lower and wrap each candidate in ``jax.jit`` in the
+    current process; the compile happens at the artifact's first call."""
 
     name = "local"
 
@@ -65,9 +69,6 @@ class LocalBuilder(Builder):
                     )
                 )
             br = out[-1]
-            metrics().observe(
-                "measure.build_s", br.build_time_s, backend=self.backend
-            )
             if trace_enabled():
                 emit(
                     "measure.build",
@@ -88,30 +89,44 @@ def time_artifact(
     warmup: int,
     timeout_s: float,
 ) -> MeasureResult:
-    """Shared timing loop: first call (compile) with timeout check, then
-    warmup, then the median of ``repeats`` timed runs."""
+    """Shared timing loop: the first call (trace, compile or cache load,
+    one run) with its timeout check, then ``warmup`` calls, then the
+    median of ``repeats`` timed runs.  ``compile_s`` is the first call's
+    wall, ``timing_s`` that of the warm-up and timed repeats."""
+    t0 = time.perf_counter()
+    compile_s = None
     try:
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(ins))
-        first = time.perf_counter() - t0
-        if first > timeout_s:
+        with span("measure.compile"):
+            jax.block_until_ready(fn(ins))
+        compile_s = time.perf_counter() - t0
+        if compile_s > timeout_s:
             # source stays "measured": this IS a completed measurement (the
             # schedule is too slow) and may be cached; source="timeout" is
             # reserved for pool batch-budget expiry, where the candidate may
             # never have run and must not be cached
             return MeasureResult(
-                float("inf"), f"timeout (first call took {first:.2f}s)"
+                float("inf"), f"timeout (first call took {compile_s:.2f}s)",
+                compile_s=compile_s,
             )
+        t1 = time.perf_counter()
         for _ in range(warmup):
             jax.block_until_ready(fn(ins))
         times = []
         for _ in range(repeats):
-            t0 = time.perf_counter()
+            t = time.perf_counter()
             jax.block_until_ready(fn(ins))
-            times.append(time.perf_counter() - t0)
-        return MeasureResult(float(np.median(times)), run_time_s=float(sum(times)))
+            times.append(time.perf_counter() - t)
+        return MeasureResult(
+            float(np.median(times)), run_time_s=float(sum(times)),
+            compile_s=compile_s, timing_s=time.perf_counter() - t1,
+        )
     except Exception as e:  # runtime failure -> rejection
-        return MeasureResult(float("inf"), f"{type(e).__name__}: {e}")
+        took = time.perf_counter() - t0
+        return MeasureResult(
+            float("inf"), f"{type(e).__name__}: {e}",
+            compile_s=took if compile_s is None else compile_s,
+            timing_s=0.0 if compile_s is None else took - compile_s,
+        )
 
 
 class LocalRunner(Runner):
@@ -149,30 +164,22 @@ class LocalRunner(Runner):
         for mi, br in zip(inputs, built):
             if not br.ok:
                 self.n_failed += 1
-                metrics().inc("measure.failed", backend=self.backend)
                 out.append(
                     MeasureResult(float("inf"), br.error, build_time_s=br.build_time_s)
                 )
                 continue
+            ins = self._inputs(mi.func)
             t0 = time.perf_counter()
             res = time_artifact(
-                br.artifact,
-                self._inputs(mi.func),
-                self.repeats,
-                self.warmup,
-                self.timeout_s,
+                br.artifact, ins, self.repeats, self.warmup, self.timeout_s
             )
-            # full run-stage wall (first call + warmup + timed repeats) —
-            # what the report's build/run/overhead breakdown consumes
+            # run-stage wall: compile_s + timing_s and the loop around them
             run_wall = time.perf_counter() - t0
             res.build_time_s = br.build_time_s
             res.meta = br.meta
             self.n_measured += 1
-            metrics().inc("measure.measured", backend=self.backend)
-            metrics().observe("measure.run_s", run_wall, backend=self.backend)
             if not res.ok:
                 self.n_failed += 1
-                metrics().inc("measure.failed", backend=self.backend)
             if trace_enabled():
                 emit(
                     "measure.run",
@@ -180,6 +187,8 @@ class LocalRunner(Runner):
                     hash=structural_hash(mi.workload_key, mi.trace),
                     ok=res.ok,
                     latency_s=res.latency_s if res.ok else None,
+                    compile_s=res.compile_s,
+                    timing_s=res.timing_s,
                     dur_s=run_wall,
                     backend=self.backend,
                     **({"error": res.error} if res.error else {}),

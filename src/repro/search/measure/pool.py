@@ -39,7 +39,7 @@ import sys
 import time
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
-from ...obs import emit, metrics, trace_enabled
+from ...obs import emit, trace_enabled
 from .hashing import structural_hash
 from .protocol import MeasureInput, MeasureResult, Runner
 
@@ -102,6 +102,8 @@ def _measure_worker(payload: dict) -> dict:
             "error": res.error,
             "build_time_s": build_s,
             "run_time_s": res.run_time_s,
+            "compile_s": res.compile_s,
+            "timing_s": res.timing_s,
             "meta": meta,
         }
     except Exception as e:
@@ -266,7 +268,6 @@ class ProcessPoolRunner(Runner):
             h = structural_hash(mi.workload_key, mi.trace)
             if h in self.quarantined:
                 self.n_quarantine_rejects += 1
-                metrics().inc("measure.quarantine_rejects", backend=self.backend)
                 if trace_enabled():
                     emit(
                         "measure.quarantine_reject",
@@ -293,11 +294,6 @@ class ProcessPoolRunner(Runner):
         ok = not out.get("error")
         build_s = float(out.get("build_time_s", 0.0))
         run_wall = float(meta.get("run_wall_s", out.get("run_time_s", 0.0)))
-        metrics().inc("measure.measured", backend=self.backend)
-        if not ok:
-            metrics().inc("measure.failed", backend=self.backend)
-        metrics().observe("measure.build_s", build_s, backend=self.backend)
-        metrics().observe("measure.run_s", run_wall, backend=self.backend)
         if trace_enabled():
             emit(
                 "measure.build",
@@ -313,13 +309,14 @@ class ProcessPoolRunner(Runner):
                 hash=h,
                 ok=ok,
                 latency_s=out["latency_s"] if ok else None,
+                compile_s=float(out.get("compile_s", 0.0)),
+                timing_s=float(out.get("timing_s", 0.0)),
                 dur_s=run_wall,
                 backend=self.backend,
                 **({"error": out["error"]} if out.get("error") else {}),
             )
 
     def _emit_timeout(self, h: str, key: str, note: str) -> None:
-        metrics().inc("measure.timeouts", backend=self.backend)
         if trace_enabled():
             emit(
                 "measure.timeout",
@@ -414,7 +411,6 @@ class ProcessPoolRunner(Runner):
             n = self.crash_counts.get(h, 0) + 1
             self.crash_counts[h] = n
             key = payload.get("workload_key", "")
-            metrics().inc("measure.crashes", backend=self.backend)
             if trace_enabled():
                 emit(
                     "measure.crash",
@@ -428,7 +424,6 @@ class ProcessPoolRunner(Runner):
             msg = f"worker crashed ({type(e).__name__}), crash {n}/{self.crash_threshold}"
             if n >= self.crash_threshold:
                 self.quarantined.add(h)
-                metrics().inc("measure.quarantined", backend=self.backend)
                 if trace_enabled():
                     emit(
                         "measure.crash_quarantine",

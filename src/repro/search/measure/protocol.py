@@ -75,13 +75,19 @@ class BuildResult:
 class MeasureResult:
     """Outcome of one measurement.  ``latency_s == inf`` means rejection.
 
-    ``meta`` is the build's lowering provenance (see ``BuildResult.meta``)
-    and flows into ``TuningRecord.meta`` for the winning candidates."""
+    ``run_time_s`` sums the timed repeats; ``compile_s`` is the first
+    call's wall (trace, compile or cache load, one run) and ``timing_s``
+    that of the warm-up and timed repeats after it (see
+    :func:`repro.search.measure.local.time_artifact`).  ``meta`` is the
+    build's lowering provenance (see ``BuildResult.meta``) and flows into
+    ``TuningRecord.meta`` for the winning candidates."""
 
     latency_s: float
     error: str = ""
     build_time_s: float = 0.0
     run_time_s: float = 0.0
+    compile_s: float = 0.0
+    timing_s: float = 0.0
     source: str = "measured"  # measured | cache | quarantine | timeout
     meta: Dict[str, Any] = field(default_factory=dict)
 

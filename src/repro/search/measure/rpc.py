@@ -37,7 +37,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from ...obs import emit, metrics, trace_enabled
+from ...obs import emit, trace_enabled
 from ..database import parse_workload_key
 from .hashing import structural_hash
 from .protocol import BuildResult, MeasureInput, MeasureResult, Runner
@@ -97,6 +97,8 @@ def encode_measure_result(r: MeasureResult) -> Dict[str, Any]:
         "error": r.error,
         "build_time_s": r.build_time_s,
         "run_time_s": r.run_time_s,
+        "compile_s": r.compile_s,
+        "timing_s": r.timing_s,
         "source": r.source,
         "meta": r.meta,
     }
@@ -108,6 +110,8 @@ def decode_measure_result(d: Dict[str, Any]) -> MeasureResult:
         error=d.get("error", ""),
         build_time_s=float(d.get("build_time_s", 0.0)),
         run_time_s=float(d.get("run_time_s", 0.0)),
+        compile_s=float(d.get("compile_s", 0.0)),
+        timing_s=float(d.get("timing_s", 0.0)),
         source=d.get("source", "measured"),
         meta=dict(d.get("meta") or {}),
     )
@@ -418,7 +422,6 @@ class RPCRunner(Runner):
             h = structural_hash(mi.workload_key, mi.trace)
             if h in self.quarantined:
                 self.n_quarantine_rejects += 1
-                metrics().inc("measure.quarantine_rejects", backend=self.backend)
                 if trace_enabled():
                     emit(
                         "measure.quarantine_reject",
@@ -483,7 +486,6 @@ class RPCRunner(Runner):
         for item in failed:
             i, h, mi = item
             self.n_retries += 1
-            metrics().inc("measure.rpc.retries", backend=self.backend)
             if trace_enabled():
                 emit(
                     "measure.rpc.retry",
@@ -518,7 +520,6 @@ class RPCRunner(Runner):
         w.batches += 1
         w.candidates += len(shard)
         w.dispatch_s += dur
-        metrics().inc("measure.rpc.batches", backend=self.backend)
         self._emit_dispatch(w, len(shard), dur, ok=True)
         return batch
 
@@ -546,7 +547,6 @@ class RPCRunner(Runner):
         if isinstance(exc, socket.timeout):
             # a hang is a timeout, not a crash — same split as the pool
             self.n_timeouts += 1
-            metrics().inc("measure.timeouts", backend=self.backend)
             if trace_enabled():
                 emit(
                     "measure.timeout",
@@ -564,7 +564,6 @@ class RPCRunner(Runner):
         self.n_crashes += 1
         n = self.crash_counts.get(h, 0) + 1
         self.crash_counts[h] = n
-        metrics().inc("measure.crashes", backend=self.backend)
         if trace_enabled():
             emit(
                 "measure.crash",
@@ -581,7 +580,6 @@ class RPCRunner(Runner):
         )
         if n >= self.crash_threshold:
             self.quarantined.add(h)
-            metrics().inc("measure.quarantined", backend=self.backend)
             if trace_enabled():
                 emit(
                     "measure.crash_quarantine",
@@ -595,7 +593,6 @@ class RPCRunner(Runner):
 
     def _no_workers_result(self, mi: MeasureInput) -> MeasureResult:
         self.n_failed += 1
-        metrics().inc("measure.failed", backend=self.backend)
         return MeasureResult(float("inf"), "no live rpc workers")
 
     # -- telemetry ----------------------------------------------------------
@@ -604,7 +601,6 @@ class RPCRunner(Runner):
         w.close()
         w.deaths += 1
         self.n_worker_deaths += 1
-        metrics().inc("measure.rpc.worker_deaths", backend=self.backend)
         if trace_enabled():
             emit(
                 "measure.rpc.worker_death",
@@ -617,7 +613,6 @@ class RPCRunner(Runner):
     def _emit_dispatch(
         self, w: _WorkerConn, n: int, dur_s: float, ok: bool
     ) -> None:
-        metrics().observe("measure.rpc.dispatch_s", dur_s, backend=self.backend)
         if trace_enabled():
             emit(
                 "measure.rpc.dispatch",
@@ -635,12 +630,8 @@ class RPCRunner(Runner):
         ok = res.ok
         run_wall = float(res.meta.get("run_wall_s", res.run_time_s))
         self.n_measured += 1
-        metrics().inc("measure.measured", backend=self.backend)
         if not ok:
             self.n_failed += 1
-            metrics().inc("measure.failed", backend=self.backend)
-        metrics().observe("measure.build_s", res.build_time_s, backend=self.backend)
-        metrics().observe("measure.run_s", run_wall, backend=self.backend)
         if trace_enabled():
             emit(
                 "measure.build",
@@ -656,6 +647,8 @@ class RPCRunner(Runner):
                 hash=h,
                 ok=ok,
                 latency_s=res.latency_s if ok else None,
+                compile_s=res.compile_s,
+                timing_s=res.timing_s,
                 dur_s=run_wall,
                 backend=self.backend,
                 **({"error": res.error} if res.error else {}),

@@ -17,7 +17,7 @@ import numpy as np
 
 from ..core.modules import SpaceGenerator, default_modules
 from ..core.tir import PrimFunc
-from ..obs import ConsoleSink, emit, metrics, span, trace_enabled
+from ..obs import ConsoleSink, emit, span, trace_enabled
 from .cost_model import GBDTCostModel
 from .database import Database
 from .distributions import DecisionDistributions
@@ -232,11 +232,6 @@ class TaskScheduler:
                         stale=self._stale_rounds[i],
                     )
                 self.rounds_run += 1
-                metrics().inc("tune.rounds", task=key)
-                if np.isfinite(s.best_latency):
-                    metrics().gauge(
-                        "search.best_latency_s", s.best_latency, task=key
-                    )
                 if self._console is not None:
                     self._console.write(
                         {

@@ -26,7 +26,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..models.registry import build_model
-from ..obs import emit, metrics, trace_enabled
+from ..obs import emit, trace_enabled
 from .config import ServeConfig, coerce_serve_config
 from .request import Request
 
@@ -125,10 +125,6 @@ class ServingEngine:
         dt = time.perf_counter() - t0
         self.stats["prefill_s"] += dt
         self.stats["prefill_tokens"] += B * S
-        m = metrics()
-        m.inc("serve.prefill_tokens", B * S, model=self.cfg.name)
-        m.observe("serve.prefill_s", dt, model=self.cfg.name)
-        m.gauge("serve.prefill_tok_s", self.prefill_tok_s, model=self.cfg.name)
         if trace_enabled():
             emit(
                 "serve.prefill",
@@ -155,7 +151,6 @@ class ServingEngine:
         for step in range(max_new - 1):
             if all(r.done for r in reqs):
                 break  # every request in flight finished: stop decoding
-            t_step = time.perf_counter()
             with self._dctx():
                 logits, cache = self._decode(
                     self.params, cache, jnp.asarray(nxt[:, None])
@@ -163,11 +158,6 @@ class ServingEngine:
             self.stats["decode_steps"] += 1
             steps_run += 1
             la = np.asarray(logits[:, 0].astype(jnp.float32))
-            m.observe(
-                "serve.decode_step_s",
-                time.perf_counter() - t_step,
-                model=self.cfg.name,
-            )
             nxt = np.array(
                 [self._sample(la[j], r.temperature) for j, r in enumerate(reqs)],
                 np.int32,
@@ -181,9 +171,6 @@ class ServingEngine:
         dt = time.perf_counter() - t0
         self.stats["decode_s"] += dt
         self.stats["decode_tokens"] += new_tokens
-        m.inc("serve.decode_tokens", new_tokens, model=self.cfg.name)
-        m.observe("serve.decode_s", dt, model=self.cfg.name)
-        m.gauge("serve.decode_tok_s", self.decode_tok_s, model=self.cfg.name)
         if trace_enabled():
             emit(
                 "serve.decode",

@@ -33,7 +33,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
 
-from ..obs import emit, metrics, trace_enabled
+from ..obs import emit, trace_enabled
 from ..search.measure.rpc import (
     PROTOCOL_VERSION,
     ProtocolError,
@@ -267,7 +267,6 @@ class ServingRouter:
             w.alive = False
             w.close()
             self.stats["worker_deaths"] += 1
-            metrics().inc("serve.router.worker_death", model=self.model)
             if trace_enabled():
                 emit(
                     "serve.router.worker_death",
@@ -281,7 +280,6 @@ class ServingRouter:
             r = self.requests[grid]
             r.resubmits += 1
             self.stats["resubmits"] += 1
-            metrics().inc("serve.router.resubmit", model=self.model)
             if trace_enabled():
                 emit(
                     "serve.router.resubmit",
@@ -324,7 +322,6 @@ class ServingRouter:
         )
         self.requests.append(r)
         self.stats["submitted"] += 1
-        metrics().inc("serve.router.submit", model=self.model)
         if trace_enabled():
             emit(
                 "serve.router.submit",
@@ -376,9 +373,6 @@ class ServingRouter:
                     w.completed += 1
                     finished += 1
                     self.stats["completed"] += 1
-                    metrics().inc(
-                        "serve.router.complete", model=self.model
-                    )
                     if trace_enabled():
                         emit(
                             "serve.router.complete",

@@ -55,7 +55,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..models.registry import build_model
-from ..obs import emit, metrics, trace_enabled
+from ..obs import emit, trace_enabled
 from .config import ServeConfig, coerce_serve_config
 from .kv import KVArena, PagedKVArena, SlotPool
 from .request import Request, ServeRequest  # noqa: F401  (re-export)
@@ -153,9 +153,6 @@ class ContinuousBatchingScheduler:
         r.mark_submitted()
         self._requests.append(r)
         self.queue.append(r)
-        metrics().gauge(
-            "serve.queue_depth", len(self.queue), model=self.cfg.name
-        )
         return r
 
     def pending(self) -> bool:
@@ -190,8 +187,6 @@ class ContinuousBatchingScheduler:
             self.stats["pages_reserved"] += self.arena.reserve(
                 slot, len(r.prompt) + r.max_new_tokens
             )
-        m = metrics()
-        m.inc("serve.admit", model=self.cfg.name)
         self.stats["admitted"] += 1
         if trace_enabled():
             emit(
@@ -223,9 +218,6 @@ class ContinuousBatchingScheduler:
         dt = time.perf_counter() - t0
         self.stats["prefill_s"] += dt
         self.stats["prefill_tokens"] += len(r.prompt)
-        m = metrics()
-        m.inc("serve.prefill_tokens", len(r.prompt), model=self.cfg.name)
-        m.observe("serve.prefill_s", dt, model=self.cfg.name)
         if trace_enabled():
             emit(
                 "serve.prefill",
@@ -243,7 +235,6 @@ class ContinuousBatchingScheduler:
         """Prompt fully processed: record TTFT, move the slot to decode."""
         r.generated.append(tok)
         r.first_token_s = time.perf_counter()
-        metrics().observe("serve.ttft_s", r.ttft_s, model=self.cfg.name)
         self._next_tok[slot] = tok
         self.active[slot] = r
         self.stats["peak_active"] = max(
@@ -262,8 +253,6 @@ class ContinuousBatchingScheduler:
         self.pool.release(slot)
         self._next_tok[slot] = 0
         self.stats["released"] += 1
-        m = metrics()
-        m.inc("serve.evict", model=self.cfg.name)
         if trace_enabled():
             emit(
                 "serve.evict",
@@ -286,18 +275,6 @@ class ContinuousBatchingScheduler:
         while self.queue and self._can_admit(self.queue[0]):
             self._admit_one()
             admitted = True
-        m = metrics()
-        m.gauge("serve.queue_depth", len(self.queue), model=self.cfg.name)
-        m.gauge(
-            "serve.slot_utilization",
-            (len(self.active) + len(self.prefilling)) / self.n_slots,
-            model=self.cfg.name,
-        )
-        if isinstance(self.arena, PagedKVArena):
-            m.gauge(
-                "serve.free_pages", self.arena.free_pages,
-                model=self.cfg.name,
-            )
         if not self.active and not self.prefilling:
             return admitted
         if not self._use_serve:
@@ -309,7 +286,6 @@ class ContinuousBatchingScheduler:
     def _decode_tick(self) -> None:
         """Legacy tick: one ``decode_step`` over the arena (all prompts
         were prefilled whole at admission)."""
-        m = metrics()
         t0 = time.perf_counter()
         with self._dctx():
             logits, cache = self._decode(
@@ -333,9 +309,6 @@ class ContinuousBatchingScheduler:
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += new_tokens
         self.stats["decode_s"] += dt
-        m.inc("serve.decode_tokens", new_tokens, model=self.cfg.name)
-        m.observe("serve.decode_step_s", dt, model=self.cfg.name)
-        m.gauge("serve.decode_tok_s", self.decode_tok_s, model=self.cfg.name)
         if trace_enabled():
             emit(
                 "serve.decode",
@@ -351,7 +324,6 @@ class ContinuousBatchingScheduler:
         """Unified tick: every live decode lane gets one token; leftover
         budget flows to prefilling requests as in-tick chunks."""
         sc = self.config
-        m = metrics()
         decode_slots = list(self.active)
         prefill_budget = max(0, sc.tick_budget - len(decode_slots))
         width = 1
@@ -416,16 +388,8 @@ class ContinuousBatchingScheduler:
         self.stats["prefill_chunks"] += len(chunked)
         if n_decode:
             self.stats["decode_steps"] += 1
-            m.inc("serve.decode_tokens", n_decode, model=self.cfg.name)
-            m.observe("serve.decode_step_s", dt, model=self.cfg.name)
-            m.gauge(
-                "serve.decode_tok_s", self.decode_tok_s,
-                model=self.cfg.name,
-            )
-        if chunked:
-            m.inc("serve.prefill_tokens", ptoks, model=self.cfg.name)
-            if n_decode:
-                self.stats["mixed_ticks"] += 1
+        if chunked and n_decode:
+            self.stats["mixed_ticks"] += 1
         if trace_enabled():
             if n_decode:
                 emit(

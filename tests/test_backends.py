@@ -326,6 +326,41 @@ class TestBatchedDispatch:
         assert kern is not None
         assert "pallas_blocks_snapped" in kern.meta
 
+    def test_search_and_dispatch_kernels_carry_the_same_identity(self):
+        from repro.integration.dispatch import DispatchContext
+        from repro.search.task_scheduler import TuneTask
+
+        db = Database(None)
+        key, func = _default_record(db, "dense", dict(m=32, n=32, k=32))
+        trace = db.best(key).trace()
+        (built,) = LocalBuilder(backend="pallas-interpret").build(
+            [MeasureInput(key, func, trace)]
+        )
+        served = DispatchContext(
+            db, tasks=[TuneTask(key=key, func=func)], backend="pallas-interpret"
+        ).kernel(key)
+        ins = random_inputs(func, 0)
+
+        def calls(jaxpr):  # pallas_call equations, inside jit too
+            for e in jaxpr.eqns:
+                if e.primitive.name == "pallas_call":
+                    yield e
+                    continue
+                for v in e.params.values():
+                    inner = getattr(v, "jaxpr", v)
+                    if hasattr(inner, "eqns"):
+                        yield from calls(inner)
+
+        def identity(fn):
+            (call,) = calls(jax.make_jaxpr(fn)(ins).jaxpr)
+            return call.params["name"], dict(call.params["metadata"])
+
+        name, meta = identity(built.artifact)
+        assert (name, meta) == identity(served.fn)
+        assert name == "dense" and meta["task"] == key
+        assert meta["blocks"] == ",".join(
+            map(str, built.meta["pallas_blocks_snapped"]))
+
 
 class TestFusedAttention:
     def test_pallas_fused_matches_reference(self, attn_qkv):

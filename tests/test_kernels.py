@@ -108,6 +108,21 @@ class TestSSDKernel:
             np.asarray(got), np.asarray(want), rtol=3e-3, atol=3e-3
         )
 
+    def test_kernel_carries_its_identity(self):
+        # Mosaic cannot lower ssd (cumsum), so the identity is checked on
+        # the pallas_call itself rather than on a compiled TPU op
+        import jax
+
+        args = (np.ones((1, 64, 2, 8), np.float32), np.ones((1, 64, 2), np.float32),
+                np.ones((1, 64, 4), np.float32), np.ones((1, 64, 4), np.float32))
+        jaxpr = jax.make_jaxpr(
+            lambda *a: ssd(*a, chunk=16, interpret=True, task="ssd/of=a_record")
+        )(*args)
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert call.params["name"] == "ssd"
+        assert dict(call.params["metadata"]) == {
+            "task": "ssd/of=a_record", "blocks": "16", "dtype": "float32"}
+
     def test_chunked_ref_equals_scan(self):
         B, S, H, P, N = 2, 64, 2, 8, 4
         x = RNG.standard_normal((B, S, H, P), dtype=np.float32)

@@ -369,3 +369,33 @@ class TestDatabaseRoundTrip:
             [TuningRecord("wl", tiny_trace(i).to_json(), 1e-3 * (i + 1)) for i in range(4)]
         )
         assert len(Database(path).top("wl", 10)) == 2
+
+
+# -- compile / timing split --------------------------------------------------
+
+
+class TestCompileTimingSplit:
+    def test_local_runner_times_first_call_apart(self):
+        from repro.core.modules import SpaceGenerator, default_modules
+        from repro.core.validator import first_valid_schedule
+        from repro.core.workloads import get_workload
+        from repro.obs import RingBufferSink, configure_tracing, disable_tracing
+
+        func = get_workload("dense", m=16, n=16, k=16)
+        sch = first_valid_schedule(func, SpaceGenerator(default_modules(False)))
+        sink = RingBufferSink()
+        configure_tracing(sink=sink)
+        try:
+            (res,) = ProtocolLocalRunner(backend="jnp").run(
+                [MeasureInput("dense/k=16/m=16/n=16", func, sch.trace, sch)]
+            )
+        finally:
+            disable_tracing()
+        assert res.ok and res.compile_s > 0 and res.timing_s > 0
+        (run,) = sink.of_type("measure.run")
+        assert run["compile_s"] == res.compile_s
+        assert run["timing_s"] == res.timing_s
+        assert res.compile_s + res.timing_s <= run["dur_s"] + 1e-6
+        # the first call ran inside its own span, before the event
+        (comp,) = sink.of_type("measure.compile")
+        assert comp["dur_s"] <= res.compile_s + 1e-6

@@ -1,6 +1,6 @@
-"""Observability stack: metrics registry, tracer/spans, pool event
-emission (timeout / crash quarantine), cache + dispatch telemetry,
-serving throughput, and the trace-folding report + CI gate."""
+"""Observability stack: tracer/spans (and their profiler annotations),
+pool event emission (timeout / crash quarantine), cache + dispatch
+telemetry, serving throughput, and the trace-folding report + CI gate."""
 
 import json
 import os
@@ -10,13 +10,10 @@ import pytest
 
 from repro.obs import (
     ConsoleSink,
-    MetricsRegistry,
     RingBufferSink,
     configure_tracing,
     disable_tracing,
     emit,
-    metrics,
-    reset_metrics,
     span,
     spearman,
     trace_enabled,
@@ -30,66 +27,14 @@ from test_measure import _keyed_worker, mi, tiny_trace
 
 @pytest.fixture
 def sink():
-    """Ring-buffer tracing scoped to one test; metrics reset too."""
-    reset_metrics()
+    """Ring-buffer tracing scoped to one test."""
     s = RingBufferSink()
     configure_tracing(sink=s)
     yield s
     disable_tracing()
-    reset_metrics()
 
 
-# -- metrics registry -------------------------------------------------------
-
-
-class TestMetrics:
-    def test_counters_fan_out_by_label(self):
-        r = MetricsRegistry()
-        r.inc("x", task="a")
-        r.inc("x", 2.0, task="a")
-        r.inc("x", task="b")
-        assert r.get_counter("x", task="a") == 3.0
-        assert r.get_counter("x", task="b") == 1.0
-        assert r.get_counter("x", task="missing") == 0.0
-
-    def test_gauge_last_write_wins(self):
-        r = MetricsRegistry()
-        assert r.get_gauge("g") is None
-        r.gauge("g", 1.0)
-        r.gauge("g", 7.5)
-        assert r.get_gauge("g") == 7.5
-
-    def test_histogram_quantiles_and_bounds(self):
-        r = MetricsRegistry()
-        for v in range(1, 101):
-            r.observe("h", float(v))
-        h = r.get_histogram("h")
-        assert h["count"] == 100
-        assert h["min"] == 1.0 and h["max"] == 100.0
-        assert h["sum"] == pytest.approx(5050.0)
-        assert h["p50"] == pytest.approx(50.5)
-        assert h["p95"] == pytest.approx(95.05)
-        assert h["p99"] == pytest.approx(99.01)
-
-    def test_snapshot_merge_and_json(self):
-        a, b = MetricsRegistry(), MetricsRegistry()
-        a.inc("c", 2.0, backend="jnp")
-        b.inc("c", 3.0, backend="jnp")
-        a.observe("h", 1.0)
-        b.observe("h", 3.0)
-        merged = MetricsRegistry.merge_snapshots(a.snapshot(), b.snapshot())
-        (c,) = merged["counters"]
-        assert c["value"] == 5.0 and c["labels"] == {"backend": "jnp"}
-        (h,) = merged["histograms"]
-        assert h["count"] == 2 and h["p50"] == pytest.approx(2.0)
-        json.loads(a.to_json())  # snapshot is plain-JSON serializable
-
-    def test_reset(self):
-        r = MetricsRegistry()
-        r.inc("c")
-        r.reset()
-        assert r.get_counter("c") == 0.0
-        assert r.snapshot() == {"counters": [], "gauges": [], "histograms": []}
+# -- rank correlation -------------------------------------------------------
 
 
 class TestSpearman:
@@ -142,6 +87,41 @@ class TestTracer:
                 raise ValueError("x")
         (e,) = sink.of_type("boom")
         assert e["n"] == 3 and e["error"] == "ValueError"
+
+    @pytest.mark.parametrize("tracing", [True, False], ids=["on", "off"])
+    def test_spans_in_profiler_trace(self, tmp_path, tracing):
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.obs.trace import _NULL_SPAN
+
+        sink = RingBufferSink()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            if tracing:
+                configure_tracing(sink=sink)
+            with span("outer") as outer:
+                with span("inner") as inner:
+                    time.sleep(0.002)
+        finally:
+            disable_tracing()
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        found = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        found[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+        if not tracing:
+            assert outer is _NULL_SPAN and inner is _NULL_SPAN
+            assert found == {}
+            return
+        # nested in the trace as in the sink
+        (o0, o1), (i0, i1) = found["repro.outer"], found["repro.inner"]
+        assert o0 <= i0 < i1 <= o1 and i1 - i0 >= 2e6
+        evs = {e["ev"]: e for e in sink.events}
+        assert evs["inner"]["parent"] == evs["outer"]["span"] == outer.id
 
     def test_jsonl_sink_and_load_events(self, tmp_path):
         path = str(tmp_path / "t.jsonl")
@@ -213,9 +193,7 @@ class TestPoolEvents:
         assert ev["key"] == "sleep"
         assert ev["hash"] == structural_hash("sleep", tiny_trace(0))
         assert ev["timeout_s"] == 0.2
-        assert metrics().get_counter(
-            "measure.timeouts", backend=r.backend
-        ) == 1.0
+        assert ev["backend"] == r.backend
 
     def test_crash_quarantine_events(self, sink):
         r = self._pool(crash_threshold=2)
@@ -261,7 +239,7 @@ class TestCacheEvents:
         assert len(sink.of_type("cache.miss")) == 1
         assert len(sink.of_type("cache.hit")) == 1
         assert sink.of_type("cache.hit")[0]["key"] == "w"
-        assert metrics().get_counter("cache.hits", backend=r.backend) == 1.0
+        assert sink.of_type("cache.hit")[0]["backend"] == r.backend
 
 
 # -- dispatch telemetry -----------------------------------------------------
@@ -323,16 +301,19 @@ class TestDispatchTelemetry:
 
 
 def _synthetic_events():
-    """10s tuning session: 1s build + 8s run, 2 rounds, dispatch + serve."""
+    """10s tuning session: 1s build + 8s run (6s compile, 2s timing),
+    2 rounds, dispatch + serve."""
     h = "abc123"
     return [
         {"ev": "trace.start", "ts": 89.0, "pid": 1},
         {"ev": "measure.build", "ts": 91.0, "dur_s": 1.0, "ok": True,
          "key": "w", "hash": h},
         {"ev": "measure.run", "ts": 95.0, "dur_s": 5.0, "ok": True,
-         "key": "w", "hash": h, "latency_s": 2e-3},
+         "key": "w", "hash": h, "latency_s": 2e-3, "compile_s": 4.0,
+         "timing_s": 1.0},
         {"ev": "measure.run", "ts": 98.0, "dur_s": 3.0, "ok": True,
-         "key": "w", "hash": "def456", "latency_s": 1e-3},
+         "key": "w", "hash": "def456", "latency_s": 1e-3, "compile_s": 2.0,
+         "timing_s": 1.0},
         {"ev": "costmodel.round", "ts": 96.0, "task": "w", "round": 1,
          "n": 4, "spearman": None, "trained": False},
         {"ev": "costmodel.round", "ts": 99.0, "task": "w", "round": 2,
@@ -361,7 +342,8 @@ class TestReportFold:
         tb = rep["time_breakdown"]
         assert rep["wall_s"] == pytest.approx(10.0)
         assert tb["build_s"] == pytest.approx(1.0)
-        assert tb["run_s"] == pytest.approx(8.0)
+        assert tb["compile_s"] == pytest.approx(6.0)
+        assert tb["timing_s"] == pytest.approx(2.0)
         assert tb["search_overhead_s"] == pytest.approx(1.0)
         assert tb["accounted_frac"] >= 0.9
 
@@ -463,8 +445,5 @@ class TestServingThroughput:
         (d,) = sink.of_type("serve.decode")
         assert p["tokens"] == 12 and d["tokens"] == 4
         assert d["steps"] == 2
-        assert metrics().get_counter(
-            "serve.decode_tokens", model=cfg.name
-        ) == 4.0
-        h = metrics().get_histogram("serve.decode_step_s", model=cfg.name)
-        assert h is not None and h["count"] == 2
+        assert p["model"] == d["model"] == cfg.name
+        assert d["dur_s"] > 0 and d["tok_s"] > 0

@@ -21,7 +21,7 @@ from repro.integration.extract import (
     extract_decode_tasks,
 )
 from repro.models.registry import build_model
-from repro.obs import metrics, reset_metrics
+from repro.obs import RingBufferSink, configure_tracing, disable_tracing
 from repro.obs.report import fold
 from repro.search.database import Database, TuningRecord
 from repro.serving import (
@@ -250,22 +250,22 @@ class TestExtractSkip:
         return rec
 
     def test_skip_increments_counter_with_reason(self, cfg):
-        reset_metrics()
-        sites = decode_attention_sites(
-            cfg,
-            [
-                self._record(scale=0.123),  # nondefault_scale
-                self._record(window="traced"),  # traced_window
-                self._record(),  # kept
-            ],
-        )
+        sink = RingBufferSink()
+        configure_tracing(sink=sink)
+        try:
+            sites = decode_attention_sites(
+                cfg,
+                [
+                    self._record(scale=0.123),  # nondefault_scale
+                    self._record(window="traced"),  # traced_window
+                    self._record(),  # kept
+                ],
+            )
+        finally:
+            disable_tracing()
         assert len(sites) == 1
-        counters = {
-            (c["name"], c["labels"].get("reason")): c["value"]
-            for c in metrics().snapshot()["counters"]
-        }
-        assert counters[("extract.skip", "nondefault_scale")] == 1
-        assert counters[("extract.skip", "traced_window")] == 1
+        reasons = [e["reason"] for e in sink.of_type("extract.skip")]
+        assert sorted(reasons) == ["nondefault_scale", "traced_window"]
 
     def test_report_folds_skip_events(self):
         events = [
