@@ -10,10 +10,16 @@ become the kernel's fused epilogue.  This is the concrete instantiation of
 "MetaSchedule constructs the space, the backend carries the decisions to
 hardware" (paper Fig 1 + Appendix A.6).
 
-Pallas needs exact tiling and Mosaic needs aligned blocks, so sampled
-tile extents are *snapped* at lower time to the nearest divisor of the
-problem shape that is a multiple of the TPU tile (8 sublanes for a
-block's second-to-last dim, 128 lanes for its last) or the whole dim.  Snapping is part of
+For a workload that lowers onto the matmul kernel the block is itself
+the trace's decision: ``UseMXU`` draws (bm, bn, bk) from
+:meth:`MatmulShape.legal_blocks` — aligned divisors of the problem whose
+VMEM footprint fits the kernel's scoped VMEM — so such a sample lowers
+unchanged.  Pallas needs exact tiling and Mosaic needs aligned blocks,
+so every other sampled tile extent (and the matmul tiles of traces
+recorded before the block was sampled) is *snapped* at lower time to the
+nearest divisor of the problem shape that is a multiple of the TPU tile
+(8 sublanes for a block's second-to-last dim, 128 lanes for its last) or
+the whole dim.  Snapping is part of
 the lowering's provenance: every ``lower_*`` path returns a meta dict with
 both the sampled and the snapped blocks, which the measurement stack
 persists into ``TuningRecord.meta`` and the dispatch layer surfaces on
@@ -29,13 +35,21 @@ the dispatch layer through ``PallasBackend.fused_attention``.
 
 from __future__ import annotations
 
+import functools
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax.numpy as jnp
 
 from ..core.schedule import BlockNode, LoopNode, Schedule, iter_nodes
 from ..core.tir import PrimFunc
 from ..kernels.flash_attention import LANE, SUBLANE, best_divisor
-from ..kernels.matmul import DEFAULT_BLOCKS
+from ..kernels.matmul import (
+    DEFAULT_BLOCKS,
+    block_vmem_bytes,
+    sublane_tile,
+    vmem_budget,
+)
 from ..kernels.softmax import DEFAULT_ROW_BLOCK
 
 # PrimFunc names this backend can lower natively (dense_* covers every
@@ -119,11 +133,6 @@ def snap_blocks(
     return tuple(best_divisor(d, b, a) for d, b, a in zip(dims, blocks, aligns))
 
 
-# (M, N, K) alignments of a matmul's blocks: bm is the sublane dim of the
-# x block, bn the lane dim of the w and out blocks, bk the lane dim of x
-MATMUL_ALIGNS = (SUBLANE, LANE, LANE)
-
-
 # Reject lowerings whose grid would explode: a 1-wide tile on a 128^3
 # matmul means 2M grid steps — useless on the MXU and pathological in
 # interpret mode.  Rejection surfaces as a failed build, which the search
@@ -140,6 +149,95 @@ def _check_grid(steps: int, blocks) -> None:
 
 
 # ---------------------------------------------------------------------------
+# The matmul kernel's block space
+# ---------------------------------------------------------------------------
+
+
+@dataclass(frozen=True)
+class MatmulShape:
+    """What a workload asks of :mod:`repro.kernels.matmul`: ``batch``
+    products of (m, k) @ (k, n) in operands of ``itemsize`` bytes, with or
+    without a bias row."""
+
+    batch: int
+    m: int
+    n: int
+    k: int
+    itemsize: int
+    bias: bool = False
+
+    @property
+    def dims(self) -> Tuple[int, int, int]:
+        return (self.m, self.n, self.k)
+
+    @property
+    def aligns(self) -> Tuple[int, int, int]:
+        """bm is the sublane dim of the x block; bn and bk are lane dims."""
+        return (sublane_tile(self.itemsize), LANE, LANE)
+
+    def grid_steps(self, blocks) -> int:
+        bm, bn, bk = blocks
+        return self.batch * (self.m // bm) * (self.n // bn) * (self.k // bk)
+
+    def vmem_bytes(self, blocks) -> int:
+        return block_vmem_bytes(*blocks, self.itemsize, bias=self.bias)
+
+    def legal_blocks(self) -> Tuple[Tuple[int, int, int], ...]:
+        """Every (bm, bn, bk) the kernel takes unchanged: each an aligned
+        divisor of its dim or the whole dim, whose buffers fit the
+        kernel's VMEM budget and whose grid stays within
+        ``MAX_GRID_STEPS``."""
+        return _legal_blocks(self)
+
+
+def _aligned_divisors(n: int, align: int) -> Tuple[int, ...]:
+    return tuple(
+        d for d in range(1, n + 1) if n % d == 0 and (d % align == 0 or d == n)
+    )
+
+
+@functools.lru_cache(maxsize=256)
+def _legal_blocks(shape: MatmulShape) -> Tuple[Tuple[int, int, int], ...]:
+    budget = vmem_budget(shape.itemsize)
+    bms, bns, bks = (
+        _aligned_divisors(d, a) for d, a in zip(shape.dims, shape.aligns)
+    )
+    return tuple(
+        (bm, bn, bk)
+        for bm in bms
+        for bn in bns
+        for bk in bks
+        if shape.vmem_bytes((bm, bn, bk)) <= budget
+        and shape.grid_steps((bm, bn, bk)) <= MAX_GRID_STEPS
+    )
+
+
+def matmul_shape(func: PrimFunc) -> Optional[MatmulShape]:
+    """The matmul-kernel problem of a workload that lowers onto
+    :mod:`repro.kernels.matmul` (``dense_*``, ``batch_matmul``), else
+    ``None``."""
+    if func.name.startswith("dense_"):
+        (m, k), n, batch = func.inputs[0].shape, func.inputs[1].shape[1], 1
+    elif func.name == "batch_matmul":
+        (batch, m, k), n = func.inputs[0].shape, func.inputs[1].shape[2]
+    else:
+        return None
+    return MatmulShape(
+        batch, m, n, k, jnp.dtype(func.inputs[0].dtype).itemsize,
+        bias=any(b.name == "bias" for b in func.inputs),
+    )
+
+
+def matmul_blocks(sch: Schedule, shape: MatmulShape):
+    """(sampled, lowered) blocks of a matmul-kernel schedule: the tile the
+    trace gives the tensorized block, and the block the kernel runs —
+    the same for a block drawn from :meth:`MatmulShape.legal_blocks`."""
+    sampled = extract_matmul_blocks(sch)
+    blocks = snap_blocks(shape.dims, sampled or DEFAULT_BLOCKS, shape.aligns)
+    return sampled, blocks
+
+
+# ---------------------------------------------------------------------------
 # Per-workload lowerings: schedule -> (fn, meta)
 # ---------------------------------------------------------------------------
 
@@ -151,13 +249,9 @@ def lower_dense(
     from ..kernels import matmul as mm
 
     func = sch.func
-    sampled = extract_matmul_blocks(sch)
-    X, W = func.inputs[0], func.inputs[1]
-    M, K = X.shape
-    N = W.shape[1]
-    blocks = snap_blocks((M, N, K), sampled or DEFAULT_BLOCKS, MATMUL_ALIGNS)
-    bm, bn, bk = blocks
-    _check_grid((M // bm) * (N // bn) * (K // bk), blocks)
+    shape = matmul_shape(func)
+    sampled, blocks = matmul_blocks(sch, shape)
+    _check_grid(shape.grid_steps(blocks), blocks)
     # epilogue from the ORIGINAL workload name (dense_<epilogue>)
     epilogue = "none"
     if func.name.startswith("dense_"):
@@ -186,14 +280,9 @@ def lower_batch_matmul(
     from ..kernels import matmul as mm
 
     func = sch.func
-    sampled = extract_matmul_blocks(sch)
-    A = func.inputs[0]
-    _, M, K = A.shape
-    N = func.inputs[1].shape[2]
-    B = A.shape[0]
-    blocks = snap_blocks((M, N, K), sampled or DEFAULT_BLOCKS, MATMUL_ALIGNS)
-    bm, bn, bk = blocks
-    _check_grid(B * (M // bm) * (N // bn) * (K // bk), blocks)
+    shape = matmul_shape(func)
+    sampled, blocks = matmul_blocks(sch, shape)
+    _check_grid(shape.grid_steps(blocks), blocks)
     meta = _block_meta("batch_matmul", sampled, blocks)
 
     def fn(inputs: Dict):

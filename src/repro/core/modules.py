@@ -139,13 +139,7 @@ class MultiLevelTiling(Module):
             return
         n_s = self.structure.count("S")
         n_r = self.structure.count("R")
-        s_splits, r_splits = [], []
-        for l in s_loops:
-            t = sch.sample_perfect_tile(l, n_s, self.max_vector)
-            s_splits.append(sch.split(l, t))
-        for l in r_loops:
-            t = sch.sample_perfect_tile(l, n_r, self.max_inner_reduce)
-            r_splits.append(sch.split(l, t))
+        s_splits, r_splits = self._split(sch, block, s_loops, r_loops, n_s, n_r)
         # reorder into groups following the structure string
         order: List[LoopRV] = []
         si, ri = 0, 0
@@ -171,6 +165,19 @@ class MultiLevelTiling(Module):
                 pass
         if self.fuse_epilogue:
             self._fuse_epilogue(sch, block, s_splits)
+
+    def _split(self, sch, block, s_loops, r_loops, n_s, n_r):
+        """Split every spatial loop ``n_s`` ways and every reduce loop
+        ``n_r`` ways by sampled perfect tiles."""
+        s_splits = [
+            sch.split(l, sch.sample_perfect_tile(l, n_s, self.max_vector))
+            for l in s_loops
+        ]
+        r_splits = [
+            sch.split(l, sch.sample_perfect_tile(l, n_r, self.max_inner_reduce))
+            for l in r_loops
+        ]
+        return s_splits, r_splits
 
     def _fuse_epilogue(self, sch: Schedule, block: BlockRV, s_splits) -> None:
         consumers = sch.get_consumers(block)
@@ -209,6 +216,11 @@ class UseMXU(MultiLevelTiling):
     contraction (``jnp.einsum``/``jnp.dot`` → MXU on TPU), and (c) stages
     operands through VMEM scratch (cache_read).  It composes with the
     generic modules exactly like Use-Tensor-Core in Figure 5.
+
+    A block that lowers onto the Pallas matmul kernel (``dense_*``,
+    ``batch_matmul``) samples the kernel's block itself
+    (``sample_mxu_block``): each loop splits into the kernel's grid, unit
+    loops, and the block, and the kernel's BlockSpecs do the staging.
     """
 
     name: str = "use_mxu"
@@ -225,7 +237,7 @@ class UseMXU(MultiLevelTiling):
         return _is_matmul_pattern(blk)
 
     def apply(self, sch: Schedule, block: BlockRV) -> None:
-        if self.stage_vmem:
+        if self.stage_vmem and self._kernel_shape(sch, block) is None:
             # staging through VMEM is itself a stochastic choice: on TPU it
             # pays for reuse, on CPU measurement it is a copy — the search
             # decides (paper §3.1: stochastic transformations, not policy)
@@ -239,6 +251,36 @@ class UseMXU(MultiLevelTiling):
                         except ScheduleError:
                             continue
         super().apply(sch, block)
+
+    @staticmethod
+    def _kernel_shape(sch: Schedule, block: BlockRV):
+        """The matmul-kernel problem ``block`` lowers onto, when the kernel
+        has a legal block for it, else ``None``."""
+        from ..backends.pallas_backend import matmul_shape
+
+        shape = matmul_shape(sch.func)
+        blk = sch.block_info(block).block
+        if shape is None or len(blk.reduce_axes) != 1:
+            return None
+        ext = [a.extent for a in blk.spatial_axes] + [blk.reduce_axes[0].extent]
+        if ext[-3:] != list(shape.dims) or not shape.legal_blocks():
+            return None
+        return shape
+
+    def _split(self, sch, block, s_loops, r_loops, n_s, n_r):
+        shape = self._kernel_shape(sch, block)
+        if shape is None:
+            return super()._split(sch, block, s_loops, r_loops, n_s, n_r)
+        f = sch.sample_mxu_block(s_loops + r_loops, shape.itemsize, shape.bias)
+        outer, inner = f[0::2], f[1::2]
+        splits = [
+            sch.split(l, [o] + [1] * (n - 2) + [b])
+            for l, o, b, n in zip(
+                s_loops + r_loops, outer, inner,
+                [n_s] * len(s_loops) + [n_r] * len(r_loops),
+            )
+        ]
+        return splits[: len(s_loops)], splits[len(s_loops):]
 
 
 # ---------------------------------------------------------------------------

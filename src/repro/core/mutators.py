@@ -39,7 +39,9 @@ def _divisors(x: int) -> List[int]:
 @dataclass
 class MutateTileSize(Mutator):
     """Move a divisor between two positions of a perfect-tile decision —
-    preserves the product so the split stays perfect."""
+    preserves the product so the split stays perfect — or move one extent
+    of a matmul kernel block to another value that keeps the block in the
+    kernel's legal set."""
 
     name = "mutate_tile_size"
 
@@ -47,11 +49,14 @@ class MutateTileSize(Mutator):
         cands = [
             i
             for i, it in enumerate(trace.insts)
-            if it.name == "sample_perfect_tile" and it.decision is not None
+            if it.name in ("sample_perfect_tile", "sample_mxu_block")
+            and it.decision is not None
         ]
         if not cands:
             return None
         idx = int(rng.choice(cands))
+        if trace.insts[idx].name == "sample_mxu_block":
+            return _mutate_mxu_block(trace, idx, rng)
         dec = list(trace.insts[idx].decision)
         n = len(dec)
         if n < 2:
@@ -72,6 +77,27 @@ class MutateTileSize(Mutator):
                 continue
             return trace.with_decision(idx, new)
         return None
+
+
+def _mutate_mxu_block(trace: Trace, idx: int, rng) -> Optional[Trace]:
+    from ..backends.pallas_backend import MatmulShape
+
+    it = trace.insts[idx]
+    batch, m, n, k = it.attrs["shape"]
+    legal = MatmulShape(
+        batch, m, n, k, it.attrs["itemsize"], it.attrs.get("bias", False)
+    ).legal_blocks()
+    dec = tuple(it.decision)
+    for axis in rng.permutation(3):
+        options = [
+            b for b in legal
+            if b[axis] != dec[axis]
+            and all(b[a] == dec[a] for a in range(3) if a != axis)
+        ]
+        if options:
+            pick = options[int(rng.integers(len(options)))]
+            return trace.with_decision(idx, list(pick))
+    return None
 
 
 @dataclass

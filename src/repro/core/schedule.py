@@ -294,6 +294,54 @@ class Schedule:
         )
         return rvs
 
+    def sample_mxu_block(
+        self,
+        loops: Sequence[LoopRV],
+        itemsize: int,
+        bias: bool = False,
+        decision: Optional[List[int]] = None,
+    ) -> List[ExprRV]:
+        """Sample the matmul kernel's block (bm, bn, bk) for a block whose
+        ``loops`` are (batch..., i, j, k): one draw from the kernel's legal
+        set (:meth:`repro.backends.pallas_backend.MatmulShape.legal_blocks`),
+        bm first, then bn among the blocks that keep bm, then bk.
+
+        Returns (outer extent, block extent) for every loop — block 1 for
+        batch loops — as the factors that split each loop into the
+        kernel's grid and its block.
+        """
+        from ..backends.pallas_backend import MatmulShape
+
+        ext = [self._find_loop(l.var)[0].extent for l in loops]
+        shape = MatmulShape(
+            int(np.prod(ext[:-3])), *ext[-3:], int(itemsize), bool(bias)
+        )
+        legal = shape.legal_blocks()
+        if decision is None:
+            decision = []
+            for axis in range(3):
+                options = sorted(
+                    {b[axis] for b in legal if list(b[:axis]) == decision}
+                )
+                decision.append(int(self.rng.choice(options)))
+        if tuple(decision) not in legal:
+            raise ScheduleError(
+                f"mxu block {decision} not legal for (m, n, k) {shape.dims}"
+            )
+        blocks = [1] * (len(ext) - 3) + list(decision)
+        rvs = []
+        for e, b in zip(ext, blocks):
+            rvs += [new_expr_rv(e // b), new_expr_rv(b)]
+        self._record(
+            "sample_mxu_block",
+            list(loops),
+            {"shape": [shape.batch, *shape.dims], "itemsize": shape.itemsize,
+             "bias": shape.bias},
+            rvs,
+            decision=list(map(int, decision)),
+        )
+        return rvs
+
     def sample_categorical(
         self,
         candidates: Sequence[int],

@@ -76,6 +76,7 @@ RVLike = Union[RV, int, float, str, None]
 
 SAMPLING_INSTRUCTIONS = (
     "sample_perfect_tile",
+    "sample_mxu_block",
     "sample_categorical",
     "sample_compute_location",
 )
@@ -285,6 +286,11 @@ def _execute(sch, name: str, inputs: List, attrs: Dict, decision) -> List[RV]:
             inputs[0],
             attrs["n"],
             attrs.get("max_innermost_factor", 16),
+            decision=decision,
+        )
+    if name == "sample_mxu_block":
+        return sch.sample_mxu_block(
+            inputs, attrs["itemsize"], attrs.get("bias", False),
             decision=decision,
         )
     if name == "sample_categorical":

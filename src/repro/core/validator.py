@@ -6,6 +6,13 @@ locations invalidated by structural changes, or resource blow-ups (the TPU
 analogue of the paper's ``thread_extent`` limits is the VMEM tile
 footprint).  Instead of constraining proposals conservatively, the search
 proposes freely and this validator rejects out-of-support samples.
+
+A schedule whose MXU-tensorized block lowers onto the Pallas matmul
+kernel (``dense_*``, ``batch_matmul``) is held to that kernel's limits:
+the grid of the block it will run and the VMEM that block's buffers
+take.  Every other schedule is held to the caps below, which bound the
+jnp backend's loop nest.  ``ValidationResult.kind`` names the limit a
+rejection hit: ``replay``, ``iteration``, ``tile`` or ``vmem``.
 """
 
 from __future__ import annotations
@@ -19,7 +26,8 @@ from .schedule import LoopNode, Schedule
 from .tir import PrimFunc
 from .trace import Trace
 
-# resource limits (TPU v5e-flavored; CPU measurement uses the same caps)
+# resource limits of every schedule that does not lower onto the matmul
+# kernel (TPU v5e-flavored; CPU measurement uses the same caps)
 MAX_ITERATIONS = 1 << 21      # fori_loop trip-count guard (measurement cost)
 MAX_TILE_ELEMS = 1 << 17      # joint tile (VREG/VMEM-resident working set)
 MAX_VMEM_BYTES = 16 << 20     # staged operand tiles must fit VMEM
@@ -34,6 +42,11 @@ class ValidationResult:
     tile_elems: int = 0
     vmem_bytes: int = 0
 
+    @property
+    def kind(self) -> str:
+        """The limit a rejection hit (the reason's first word)."""
+        return self.reason.split(" ", 1)[0].rstrip(":")
+
 
 def validate_trace(func: PrimFunc, trace: Trace) -> ValidationResult:
     """Replay ``trace`` on a fresh schedule and check structural limits."""
@@ -47,6 +60,30 @@ def validate_trace(func: PrimFunc, trace: Trace) -> ValidationResult:
 
 def validate_schedule(sch: Schedule) -> ValidationResult:
     from ..backends.jnp_backend import _tile_suffix, estimate_iteration_count
+    from ..backends.pallas_backend import (
+        MAX_GRID_STEPS,
+        find_tensorized_block,
+        matmul_blocks,
+        matmul_shape,
+    )
+    from ..kernels.matmul import vmem_budget
+
+    shape = matmul_shape(sch.func)
+    mxu = find_tensorized_block(sch)
+    if shape is not None and mxu.annotations.get("tensorize") == "mxu":
+        _, blocks = matmul_blocks(sch, shape)
+        steps = shape.grid_steps(blocks)
+        tile = int(np.prod(blocks))
+        if steps > MAX_GRID_STEPS:
+            return ValidationResult(
+                False, None, f"iteration grid {steps} > {MAX_GRID_STEPS}", steps
+            )
+        vmem, budget = shape.vmem_bytes(blocks), vmem_budget(shape.itemsize)
+        if vmem > budget:
+            return ValidationResult(
+                False, None, f"vmem {vmem} > {budget}", steps, tile, vmem
+            )
+        return ValidationResult(True, sch, "", steps, tile, vmem)
 
     iters = estimate_iteration_count(sch)
     if iters > MAX_ITERATIONS:

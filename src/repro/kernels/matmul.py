@@ -24,6 +24,42 @@ from .ref import apply_epilogue
 
 DEFAULT_BLOCKS = (128, 128, 128)  # MXU-native tiles
 
+# Mosaic's default scoped VMEM, which these kernels compile with: their
+# pallas_call sets no vmem_limit_bytes.
+VMEM_LIMIT_BYTES = 16 << 20
+# VMEM left to Mosaic's own scratch (the dot's operand relayouts) beyond
+# the buffers block_vmem_bytes counts, per 32-bit word an operand element
+# packs into.  Compiled for a described v5e at stablelm-3b widths, float32
+# blocks needed up to 1.3 MiB more than counted and bfloat16 blocks up to
+# 4.8 MiB more; no bfloat16 block counted under 13 MiB failed.
+MOSAIC_SCRATCH_BYTES = 2 << 20
+
+
+def block_vmem_bytes(
+    bm: int, bn: int, bk: int, itemsize: int, *, out_itemsize: int = 0,
+    bias: bool = False,
+) -> int:
+    """VMEM the matmul kernel holds for blocks (bm, bn, bk): the x and w
+    blocks and the output block, each double-buffered by the pipeline,
+    the float32 accumulator, and the (1, bn) bias block padded to a
+    sublane tile."""
+    out_itemsize = out_itemsize or itemsize
+    n = 2 * (bm * bk + bk * bn) * itemsize + 2 * bm * bn * out_itemsize
+    n += 4 * bm * bn
+    if bias:
+        n += 2 * sublane_tile(itemsize) * bn * itemsize
+    return n
+
+
+def sublane_tile(itemsize: int) -> int:
+    """Rows of one (sublane, lane) tile: 8 of 32 bits, more when packed."""
+    return 8 * 4 // itemsize
+
+
+def vmem_budget(itemsize: int) -> int:
+    """Most VMEM a block's buffers may count (:func:`block_vmem_bytes`)."""
+    return VMEM_LIMIT_BYTES - MOSAIC_SCRATCH_BYTES * 4 // itemsize
+
 
 def _matmul_kernel(
     x_ref, w_ref, b_ref, o_ref, acc_ref, *, nk: int, epilogue: str, softcap: float

@@ -48,6 +48,9 @@ def decision_site_key(inst: Instruction) -> Optional[str]:
     Returns ``None`` for instructions that are not sampling decisions.
     Tile splits key on ``(extent, n, max_innermost)`` — the extent is
     recovered from the recorded decision, so no loop context is needed;
+    a matmul kernel block keys on everything its legal set is computed
+    from (batch, m, n, k, operand width, bias), so a learned draw is
+    always legal;
     categoricals key on their candidate tuple; compute locations pool into
     one site per decision kind (their support is state-dependent, so the
     learned part is the global inline/root/loop-depth preference).
@@ -59,6 +62,12 @@ def decision_site_key(inst: Instruction) -> Optional[str]:
         n = inst.attrs.get("n", len(inst.decision))
         maxin = inst.attrs.get("max_innermost_factor", 16)
         return f"tile/extent={extent}/n={n}/max={maxin}"
+    if inst.name == "sample_mxu_block":
+        shape = ",".join(str(d) for d in inst.attrs["shape"])
+        return (
+            f"mxu/shape={shape}/itemsize={inst.attrs['itemsize']}"
+            f"/bias={int(inst.attrs.get('bias', False))}"
+        )
     if inst.name == "sample_categorical":
         cands = ",".join(str(c) for c in inst.attrs.get("candidates", []))
         return f"cat/candidates={cands}"

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import math
 import time
+from collections import Counter
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -172,14 +173,22 @@ class EvolutionarySearch:
             meta.update(res.meta)
         return meta
 
-    def _validated(self, trace: Trace) -> Optional[Candidate]:
+    def _validated(
+        self, trace: Trace, rejected: Optional[Counter] = None
+    ) -> Optional[Candidate]:
+        """The candidate of a valid ``trace``, else ``None``; a rejection
+        is counted by its kind in ``rejected``."""
         res = validate_trace(self.func, trace)
         if not res.ok:
+            if rejected is not None:
+                rejected[res.kind] += 1
             return None
         feats = extract_features(res.schedule)
         return Candidate(res.schedule.trace, res.schedule, feats)
 
-    def _learned_variant(self, trace: Trace) -> Optional[Candidate]:
+    def _learned_variant(
+        self, trace: Trace, rejected: Optional[Counter] = None
+    ) -> Optional[Candidate]:
         """Re-draw a fresh trace's decisions from the learned distributions.
 
         Returns ``None`` when no site produced an override or the overridden
@@ -188,13 +197,14 @@ class EvolutionarySearch:
         decs = self.dists.decisions_for(trace, self.rng)
         if not decs:
             return None
-        return self._validated(trace.with_decisions(decs))
+        return self._validated(trace.with_decisions(decs), rejected)
 
     def _sample_initial(self, n: int) -> List[Candidate]:
         t0 = time.perf_counter()
         out: List[Candidate] = []
         tries = 0
         learned = 0
+        rejected: Counter = Counter()
         use_learned = (
             self.cfg.learned_sampling
             and self.cfg.learned_frac > 0
@@ -206,11 +216,11 @@ class EvolutionarySearch:
             sch = self.space.generate(self.func, seed=seed)
             cand = None
             if use_learned and self.rng.random() < self.cfg.learned_frac:
-                cand = self._learned_variant(sch.trace)
+                cand = self._learned_variant(sch.trace, rejected)
                 if cand is not None:
                     learned += 1
             if cand is None:
-                cand = self._validated(sch.trace)
+                cand = self._validated(sch.trace, rejected)
             if cand is not None:
                 out.append(cand)
         self.learned_samples += learned
@@ -223,6 +233,7 @@ class EvolutionarySearch:
                 valid=len(out),
                 learned=learned,
                 tries=tries,
+                rejected=dict(rejected),
                 dur_s=time.perf_counter() - t0,
             )
         return out

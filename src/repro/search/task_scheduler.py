@@ -44,9 +44,11 @@ class TaskScheduler:
     After warmup, rounds go to the task whose recent best-latency slope —
     weighted by its extracted occurrence count — promises the largest
     end-to-end gain; exact gradient ties break uniformly at random.  A
-    task that fails to improve for ``patience`` consecutive rounds is
-    considered plateaued and stops receiving trials; tuning ends early
-    once every task has plateaued.
+    task whose rounds have measured nothing yet (its space sampled no
+    valid candidate, or every candidate failed) has no slope and waits
+    until the measuring tasks plateau.  A task that fails to improve for
+    ``patience`` consecutive rounds is considered plateaued and stops
+    receiving trials; tuning ends early once every task has plateaued.
 
     All tasks share **one** cost model and **one** learned-distribution
     registry: the model pools every task's samples over shape-generic
@@ -138,13 +140,14 @@ class TaskScheduler:
         t = self.tasks[i]
         if self._stale_rounds[i] >= self.patience:
             return float("-inf")  # plateaued: stop allocating trials
-        if not self._initialized[i] or not np.isfinite(s.best_latency):
+        if not self._initialized[i]:
             return float("inf")  # cold tasks first
-        h = s.history
-        if len(h) < 2:
-            return float("inf")
+        if not np.isfinite(s.best_latency):
+            # its rounds so far measured nothing: no gain to extrapolate,
+            # so it waits until the tasks that are improving plateau
+            return 0.0
         # recent slope of best latency, weighted by occurrence count x latency
-        window = h[-8:]
+        window = s.history[-8:]
         d = window[0][1] - window[-1][1]
         return t.weight * max(d, 0.0) + 1e-9 * t.weight * s.best_latency
 

@@ -187,7 +187,7 @@ class TestAdaptiveShardingPolicy:
 
 class TestPallasBackendExtraction:
     def test_divisor_snap(self):
-        from repro.backends.pallas_backend import MATMUL_ALIGNS, snap_blocks
+        from repro.backends.pallas_backend import MatmulShape, snap_blocks
         from repro.kernels.flash_attention import best_divisor
 
         assert best_divisor(128, 100, 1) == 128
@@ -196,9 +196,10 @@ class TestPallasBackendExtraction:
         assert best_divisor(7, 100, 1) == 7
         # Mosaic's rule: 8-aligned rows, 128-aligned lanes, or the full
         # dim.  d_model=576 has no 128-aligned divisor but itself.
-        assert snap_blocks((128, 576, 576), (128, 128, 128), MATMUL_ALIGNS) == (
+        f32 = MatmulShape(1, 128, 576, 576, 4).aligns
+        assert snap_blocks((128, 576, 576), (128, 128, 128), f32) == (
             128, 576, 576
         )
-        assert snap_blocks((24, 1536, 1536), (4, 100, 300), MATMUL_ALIGNS) == (
+        assert snap_blocks((24, 1536, 1536), (4, 100, 300), f32) == (
             8, 128, 256
         )

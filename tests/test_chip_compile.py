@@ -1,8 +1,8 @@
 """Compile-only checks of the Pallas kernels for a described TPU v5e chip.
 
 Nothing runs: each test lowers one kernel at smollm-135m widths
-(d_model=576, d_ff=1536, vocab 49152, 9 query / 3 kv heads, head_dim 64)
-and compiles it with the TPU compiler for a chip that is described, not
+(d_model=576, d_ff=1536, vocab 49152, 9 query / 3 kv heads, head_dim 64),
+or the dense kernel at stablelm-3b widths, and compiles it with the TPU compiler for a chip that is described, not
 attached.  This is what the Pallas interpreter cannot check: Mosaic's
 block-shape and layout rules.  Each kernel also carries its identity into
 the compiled op, as the profiler's trace prints it: the op is named by its
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.backends.pallas_backend import MATMUL_ALIGNS, snap_blocks
+from repro.backends.pallas_backend import MatmulShape, snap_blocks
 from repro.kernels import flash_attention as fa
 from repro.kernels import matmul as mm
 from repro.kernels import softmax as sm
@@ -82,17 +82,40 @@ F32 = jnp.float32
     "k,n", [(D, DFF), (DFF, D), (D, VOCAB)], ids=["up", "down", "unembed"]
 )
 def test_dense_default_blocks(one_chip, k, n):
-    blocks = snap_blocks((TOKENS, n, k), mm.DEFAULT_BLOCKS, MATMUL_ALIGNS)
+    blocks = snap_blocks((TOKENS, n, k), mm.DEFAULT_BLOCKS,
+                         MatmulShape(1, TOKENS, n, k, 4).aligns)
     _compile(
         lambda x, w: mm.matmul(x, w, block_sizes=blocks, interpret=False),
         one_chip, ((TOKENS, k), F32), ((k, n), F32),
     )
 
 
+# (m, k, n) of stablelm-3b's dense ops at 4 x 2048 tokens
+STABLELM_DENSE = {
+    "up": (8192, 2560, 6912), "qkvo": (8192, 2560, 2560),
+    "down": (8192, 6912, 2560), "unembed": (8192, 2560, 50304),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("op", sorted(STABLELM_DENSE))
+def test_dense_largest_legal_block(one_chip, op, dtype):
+    # the block of the matmul space that takes the most VMEM compiles
+    # within the kernel's default scoped VMEM
+    m, k, n = STABLELM_DENSE[op]
+    shape = MatmulShape(1, m, n, k, jnp.dtype(dtype).itemsize)
+    blocks = max(shape.legal_blocks(), key=shape.vmem_bytes)
+    _compile(
+        lambda x, w: mm.matmul(x, w, block_sizes=blocks, interpret=False),
+        one_chip, ((m, k), dtype), ((k, n), dtype),
+    )
+
+
 def test_batch_matmul(one_chip):
     # attention scores as the extractor keys them: (B*KVH, G*S, D) x (D, S)
-    blocks = snap_blocks((H // KVH * TOKENS, TOKENS, HD), mm.DEFAULT_BLOCKS,
-                         MATMUL_ALIGNS)
+    shape = MatmulShape(KVH, H // KVH * TOKENS, TOKENS, HD, 4)
+    blocks = snap_blocks(shape.dims, mm.DEFAULT_BLOCKS, shape.aligns)
     _compile(
         lambda a, b: mm.batch_matmul(a, b, block_sizes=blocks, interpret=False),
         one_chip,

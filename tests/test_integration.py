@@ -299,6 +299,19 @@ class TestTaskScheduler:
         picks = {sched._pick_task() for _ in range(40)}
         assert len(picks) > 1  # not always argmax index 0
 
+    def test_task_with_nothing_measured_waits(self):
+        # a task whose rounds found nothing to measure gets rounds only
+        # after the tasks that measure plateau
+        sched = TaskScheduler(
+            _tiny_tasks(2), runner=FakeRunner(), config=SMALL, patience=2
+        )
+        sched._initialized = [True, True]
+        sched.searches[0].best_latency = 1e-3
+        sched.searches[0].history = [(1, 1e-3)]
+        assert sched._pick_task() == 0
+        sched._stale_rounds[0] = 2
+        assert sched._pick_task() == 1
+
     def test_plateaued_task_stops_receiving_trials(self):
         sched = TaskScheduler(
             _tiny_tasks(2), runner=FakeRunner(), config=SMALL, patience=1

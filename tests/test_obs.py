@@ -447,3 +447,35 @@ class TestServingThroughput:
         assert d["steps"] == 2
         assert p["model"] == d["model"] == cfg.name
         assert d["dur_s"] > 0 and d["tok_s"] > 0
+
+
+class TestSampleEvents:
+    """``search.sample`` counts each validator rejection by its kind."""
+
+    def _sample(self, func, n, use_mxu):
+        from repro.core.modules import SpaceGenerator, default_modules
+        from repro.search.evolutionary import EvolutionarySearch, SearchConfig
+
+        search = EvolutionarySearch(
+            func, SpaceGenerator(default_modules(use_mxu=use_mxu)),
+            config=SearchConfig(seed=3),
+        )
+        return search._sample_initial(n)
+
+    def test_matmul_kernel_samples_are_all_valid(self, sink):
+        from repro.core import workloads as W
+
+        cands = self._sample(W.dense(m=8192, n=6912, k=2560), 6, True)
+        (ev,) = sink.of_type("search.sample")
+        assert len(cands) == ev["valid"] == ev["tries"] == 6
+        assert ev["rejected"] == {}
+
+    def test_rejections_counted_by_kind(self, sink):
+        from repro.core import workloads as W
+
+        # no kernel lowering: the jnp caps reject most of this space
+        self._sample(W.gmm(n=1024, m=1024, k=1024), 3, False)
+        (ev,) = sink.of_type("search.sample")
+        assert ev["tries"] > ev["valid"]
+        assert set(ev["rejected"]) <= {"replay", "iteration", "tile", "vmem"}
+        assert sum(ev["rejected"].values()) == ev["tries"] - ev["valid"]
