@@ -1,9 +1,10 @@
 """Shared pieces of one benchmark run: the cell's files, the device check,
 the seeded weights, tuning, host spans and the profiler.
 
-Nothing here names a cell, configuration, traffic mix or metric: those
-are found by the names ``BENCHMARK.json`` gives them, as files under
-``configs/``, ``workloads/``, ``traffic/``, ``jobs/`` and ``metrics/``.
+Nothing here names a cell, configuration, architecture, traffic mix or
+metric: those are found by the names ``BENCHMARK.json`` and the
+configuration files give them, as files under ``configs/``, ``refs/``,
+``workloads/``, ``traffic/``, ``jobs/`` and ``metrics/``.
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+REFS = HERE / "refs"
 
 
 class NotRunnable(Exception):
@@ -117,36 +119,46 @@ def memory_peak() -> Optional[int]:
 # The model under test
 # ---------------------------------------------------------------------------
 
-# configuration-file key -> the program's ModelConfig field
-SHAPE_FIELDS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab",
-}
+def reference(conf: Dict):
+    """The reference module the configuration file names:
+    ``refs/<reference>.py``, loaded once."""
+    path = REFS / f"{conf['reference']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"{conf['name']}: no reference module {conf['reference']!r}, "
+            f"looked for {path}"
+        )
+    mod = sys.modules.get(f"bench_{path.stem}")
+    if mod is not None and Path(mod.__file__) == path:
+        return mod
+    return load_module(path)
+
+
+def prng_key(seed: int):
+    """A key from any whole number (the low and high 32 bits both count)."""
+    import jax
+    import numpy as np
+
+    seed = int(seed) % (1 << 64)
+    key = jax.random.PRNGKey(np.uint32(seed & 0xFFFFFFFF))
+    return jax.random.fold_in(key, np.uint32(seed >> 32))
 
 
 def program_config(conf: Dict):
     """The program's ModelConfig for a configuration file: the named
-    repository config with the file's ``overrides``; every shape key of
-    the file must agree with it."""
+    repository config with the file's ``overrides``; every field the
+    file's reference module describes must agree with it."""
     from repro.configs.base import get_config
 
     cfg = dataclasses.replace(
         get_config(conf["repro_config"]), **conf.get("overrides", {})
     )
-    for key, field in SHAPE_FIELDS.items():
-        if getattr(cfg, field) != conf[key]:
+    for field, want in reference(conf).program_fields(conf).items():
+        if getattr(cfg, field) != want:
             raise ValueError(
-                f"{conf['name']}: {key}={conf[key]} but the program runs "
-                f"{field}={getattr(cfg, field)}"
+                f"{conf['name']}: the file gives {field}={want!r} but the "
+                f"program runs {field}={getattr(cfg, field)!r}"
             )
-    from model_ref import norm_rope
-
-    if (cfg.norm_eps, cfg.rope_theta) != norm_rope(conf):
-        raise ValueError(f"{conf['name']}: norm epsilon or rotary base differ")
     return cfg
 
 
@@ -155,10 +167,8 @@ def make_params(conf: Dict, seed: int, model) -> Dict:
     against the shapes and dtypes the program's model expects."""
     import jax
 
-    import model_ref
-    from work import sizes
-
-    params = model_ref.make_params(sizes(conf), seed)
+    ref = reference(conf)
+    params = ref.make_params(ref.sizes(conf), seed)
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     got_s, want_s = jax.tree.structure(params), jax.tree.structure(want)
     if got_s != want_s:

@@ -20,14 +20,13 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import harness
-import model_ref
 import traffic
-import work
 
 
 @dataclass
 class State:
-    z: Dict[str, int]
+    ref: Any
+    z: Any
     model: Any
     params: Any
     ctx: Any
@@ -44,7 +43,8 @@ def setup(r) -> State:
 
     cfg = harness.program_config(r.conf)
     model = build_model(cfg)
-    z = work.sizes(r.conf)
+    ref = harness.reference(r.conf)
+    z = ref.sizes(r.conf)
     mix, sp = r.traffic, r.spans
     with sp("weights"):
         params = harness.make_params(r.conf, r.seed, model)
@@ -61,18 +61,18 @@ def setup(r) -> State:
         mode="best", backend=r.backend,
     )
     fwd = jax.jit(lambda p, t: model.forward(p, tokens=t))
-    batch = traffic.packed_batch_fn(mix, z["V"], r.seed)
+    batch = traffic.packed_batch_fn(mix, z.V, r.seed)
     with sp("compile"), ctx:
         jax.block_until_ready(fwd(params, batch(0)))
     r.obs["dispatch"] = harness.dispatch_counts(ctx)
-    return State(z, model, params, ctx, fwd, batch)
+    return State(ref, z, model, params, ctx, fwd, batch)
 
 
 def reseed(r, st: State, seed: int) -> None:
     """New weights and token stream from ``seed``, same compiled program."""
     st.params = None
     st.params = harness.make_params(r.conf, seed, st.model)
-    st.batch = traffic.packed_batch_fn(r.traffic, st.z["V"], seed)
+    st.batch = traffic.packed_batch_fn(r.traffic, st.z.V, seed)
 
 
 def steps(r, st: State, first: int, count: int = 0, seconds: float = 0.0,
@@ -123,7 +123,6 @@ def compare(r, st: State, kept: Dict, quant: Optional[str] = None) -> Dict:
     computed at that precision, put in the program's place."""
     import jax.numpy as jnp
 
-    eps, theta = model_ref.norm_rope(r.conf)
     out = {"logits_rel_err": 0.0, "logits_kl": 0.0, "bad": 0}
     if quant:
         out.update(control_rel_err=0.0, control_kl=0.0)
@@ -140,14 +139,12 @@ def compare(r, st: State, kept: Dict, quant: Optional[str] = None) -> Dict:
                 worst("logits_rel_err", float("inf"))
                 worst("logits_kl", float("inf"))
                 continue
-            ref = model_ref.forward(st.z, st.params, toks[b:b + 1], eps, theta)
+            ref = st.ref.forward(st.z, st.params, toks[b:b + 1])
             norm = jnp.linalg.norm(ref)
             worst("logits_rel_err", float(jnp.linalg.norm(got - ref) / norm))
             worst("logits_kl", _kl(ref, got))
             if quant:
-                low = model_ref.forward(
-                    st.z, st.params, toks[b:b + 1], eps, theta, quant
-                )
+                low = st.ref.forward(st.z, st.params, toks[b:b + 1], quant)
                 worst("control_rel_err", float(jnp.linalg.norm(low - ref) / norm))
                 worst("control_kl", _kl(ref, low))
     return out
@@ -172,7 +169,7 @@ def run(r) -> None:
     wl, mix = r.workload, r.traffic
     st = setup(r)
     z, B, S = st.z, int(mix["batch"]), int(mix["seq"])
-    r.obs["step_flops"] = work.forward_flops(z, B, S)
+    r.obs["step_flops"] = st.ref.forward_flops(z, B, S)
     if r.trace:
         # tuned against XLA alone, on the same batches, before the window
         xla = jax.jit(lambda p, t: st.model.forward(p, tokens=t))

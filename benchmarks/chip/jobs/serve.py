@@ -24,7 +24,6 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 import harness
-import model_ref
 import traffic
 import work
 
@@ -32,7 +31,8 @@ import work
 @dataclass
 class State:
     cfg: Any
-    z: Dict[str, int]
+    ref: Any
+    z: Any
     params: Any
     ctx: Any
     sched: Any
@@ -48,7 +48,8 @@ def setup(r) -> State:
 
     cfg = harness.program_config(r.conf)
     model = build_model(cfg)
-    z = work.sizes(r.conf)
+    ref = harness.reference(r.conf)
+    z = ref.sizes(r.conf)
     sv, sp = r.workload["serving"], r.spans
     with sp("weights"):
         params = harness.make_params(r.conf, r.seed, model)
@@ -93,7 +94,7 @@ def setup(r) -> State:
             arena.release_slot(0)
         jax.block_until_ready(sched.arena.cache)
     r.obs["dispatch"] = harness.dispatch_counts(ctx)
-    return State(cfg, z, params, ctx, sched)
+    return State(cfg, ref, z, params, ctx, sched)
 
 
 def reset(st: State, params, seed: int) -> None:
@@ -164,7 +165,7 @@ def window(r, st: State, arrivals, seconds: float, account: bool) -> Dict:
                     done = q.prefill_done
                     if done > pre[id(q)]:
                         lanes.append((done - pre[id(q)], done))
-                flops, nbytes = work.serve_tick(z, lanes)
+                flops, nbytes = st.ref.serve_tick(z, lanes)
                 ideal += work.ideal_s(flops, nbytes, r.peak)
     t_end = time.perf_counter()
     # due before the close but never submitted (a tick overran the close)
@@ -211,7 +212,6 @@ def served_gaps(r, st: State, recs: List[Dict], seed: int,
     pick = np.random.default_rng([seed % (1 << 64), 9])
     order = [done[0]] + [done[1:][j] for j in pick.permutation(len(done) - 1)]
     want = int(r.workload["check"]["sample_tokens"])
-    eps, theta = model_ref.norm_rope(r.conf)
     worst, worst_q, count = 0.0, 0.0, 0
     for q in order:
         if count >= want:
@@ -219,12 +219,12 @@ def served_gaps(r, st: State, recs: List[Dict], seed: int,
         gen = np.asarray(q.generated, np.int32)
         seq = np.concatenate([np.asarray(q.prompt, np.int32), gen])[None, :-1]
         rows = slice(len(q.prompt) - 1, seq.shape[1])
-        ref = model_ref.forward(st.z, st.params, seq, eps, theta)[0, rows]
+        ref = st.ref.forward(st.z, st.params, seq)[0, rows]
         best = jnp.max(ref, axis=-1)
         gap = best - jnp.take_along_axis(ref, jnp.asarray(gen)[:, None], 1)[:, 0]
         worst = max(worst, float(jnp.max(gap)))
         if quant:
-            low = model_ref.forward(st.z, st.params, seq, eps, theta, quant)
+            low = st.ref.forward(st.z, st.params, seq, quant)
             top = jnp.argmax(low[0, rows], axis=-1)
             gq = best - jnp.take_along_axis(ref, top[:, None], 1)[:, 0]
             worst_q = max(worst_q, float(jnp.max(gq)))
@@ -238,7 +238,7 @@ def served_gaps(r, st: State, recs: List[Dict], seed: int,
 def run(r) -> None:
     wl = r.workload
     st = setup(r)
-    vocab = st.z["V"]
+    vocab = st.z.V
     arrivals = traffic.open_loop(r.traffic, wl["rate"], r.seconds, vocab, r.seed)
     r.obs["setup_s"] = time.perf_counter() - r.t_start
     w = window(r, st, arrivals, r.seconds, account=r.trace)

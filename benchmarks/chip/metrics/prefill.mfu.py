@@ -1,5 +1,6 @@
-"""Model FLOPs of the window's forwards (work.forward_flops) per second of
-the window, in percent of the chip's bf16 peak."""
+"""Model FLOPs of the window's forwards (the configuration's reference
+module's forward_flops) per second of the window, in percent of the
+chip's bf16 peak."""
 
 
 def read(obs):

@@ -1,7 +1,7 @@
 """Sum over the traced run's window ticks of the least time each tick's
 real work needs (the larger of its FLOPs over peak and its weight plus
-live KV bytes over HBM bandwidth, work.serve_tick), over the window, in
-percent."""
+live KV bytes over HBM bandwidth, the reference module's serve_tick),
+over the window, in percent."""
 
 
 def read(obs):

@@ -51,7 +51,7 @@ def serve_readings(r, job, seeds, n_control, seconds):
     wl = r.workload
     for i, seed in enumerate(seeds):
         job.reset(st, harness.make_params(r.conf, seed, st.sched.model), seed)
-        arr = traffic.open_loop(r.traffic, wl["rate"], seconds, st.z["V"], seed)
+        arr = traffic.open_loop(r.traffic, wl["rate"], seconds, st.z.V, seed)
         w = job.window(r, st, arr, seconds, account=False)
         t_end = _t.perf_counter() + float(wl["check"]["drain_s"])
         while st.sched.pending() and _t.perf_counter() < t_end:

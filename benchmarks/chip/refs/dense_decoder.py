@@ -1,4 +1,6 @@
-"""Seeded weights and the plain float32 reference of a dense decoder.
+"""Seeded weights, step counts and the plain float32 reference of a dense
+decoder: the reference module of every configuration file that names
+``"reference": "dense_decoder"`` (the interface is in ``refs/__init__.py``).
 
 The weights are made here, on the device, in one jitted call from the
 seed, in bf16 (the dtype the configurations serve in) and laid out as the
@@ -17,42 +19,92 @@ beside the weights, and imports nothing of the program.
 ``quant="int8"`` is the control: the same reference with every linear
 layer computed from int8 operands (weights per output channel,
 activations per token, symmetric), the precision one step below bf16.
+
+The step counts are what the algorithm needs in bf16 (``work.py``'s
+rule): every matmul of every layer, the unembedding, and causal
+attention over the pairs its mask admits.
 """
 
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from harness import prng_key
+from work import BF16, causal_pairs
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
-
-def prng_key(seed: int):
-    """A key from any whole number (the low and high 32 bits both count)."""
-    seed = int(seed) % (1 << 64)
-    key = jax.random.PRNGKey(np.uint32(seed & 0xFFFFFFFF))
-    return jax.random.fold_in(key, np.uint32(seed >> 32))
-
-
-def norm_rope(conf: Dict) -> Tuple[float, float]:
-    """(norm epsilon, rotary base) of a configuration file."""
-    eps = conf.get("rms_norm_eps", conf.get("layer_norm_eps"))
-    return float(eps), float(conf["rope_theta"])
+# the configuration file's keys as ``small`` shrinks them for the CPU
+# tests, and the program's config fields that must follow
+SMALL = dict(
+    num_hidden_layers=2, hidden_size=48, num_attention_heads=3,
+    num_key_value_heads=1, intermediate_size=96, vocab_size=256,
+)
+SMALL_PROGRAM = dict(
+    n_layers=2, d_model=48, n_heads=3, n_kv_heads=1, d_ff=96, vocab=256,
+    head_dim=16,
+)
 
 
-def _shape_key(z: Dict[str, int]) -> Tuple:
-    return tuple(sorted(z.items()))
+@dataclass(frozen=True)
+class Sizes:
+    """Layers, width, query and kv heads, head size, MLP width, vocabulary
+    held on this chip; norm epsilon and rotary base."""
+
+    L: int
+    D: int
+    H: int
+    KVH: int
+    hd: int
+    F: int
+    V: int
+    eps: float
+    theta: float
+
+
+def sizes(conf: Dict) -> Sizes:
+    """The shape keys of a configuration file, under short names."""
+    d = conf["hidden_size"]
+    h = conf["num_attention_heads"]
+    return Sizes(
+        L=conf["num_hidden_layers"],
+        D=d,
+        H=h,
+        KVH=conf["num_key_value_heads"],
+        hd=conf.get("head_dim") or d // h,
+        F=conf["intermediate_size"],
+        V=conf["vocab_size"],
+        eps=float(conf.get("rms_norm_eps", conf.get("layer_norm_eps"))),
+        theta=float(conf["rope_theta"]),
+    )
+
+
+def program_fields(conf: Dict) -> Dict:
+    """The program's ModelConfig fields this reference describes."""
+    z = sizes(conf)
+    return {
+        "family": "dense", "n_layers": z.L, "d_model": z.D, "n_heads": z.H,
+        "n_kv_heads": z.KVH, "head_dim": z.hd, "d_ff": z.F, "vocab": z.V,
+        "norm_eps": z.eps, "rope_theta": z.theta,
+    }
+
+
+def small(conf: Dict) -> Dict:
+    """The configuration file shrunk for the CPU: two layers of width 48."""
+    out = dict(conf, **SMALL)
+    out["overrides"] = dict(conf.get("overrides", {}), **SMALL_PROGRAM)
+    return out
 
 
 @partial(jax.jit, static_argnums=0)
-def _init(zk: Tuple, key) -> Dict:
-    z = dict(zk)
-    L, D, H, KVH, hd, F, V = (z[k] for k in ("L", "D", "H", "KVH", "hd", "F", "V"))
+def _init(z: Sizes, key) -> Dict:
+    L, D, H, KVH, hd, F, V = z.L, z.D, z.H, z.KVH, z.hd, z.F, z.V
     ks = jax.random.split(key, 10)
 
     def normal(k, shape, fan_in):
@@ -83,8 +135,8 @@ def _init(zk: Tuple, key) -> Dict:
     }
 
 
-def make_params(z: Dict[str, int], seed: int) -> Dict:
-    return _init(_shape_key(z), prng_key(seed))
+def make_params(z: Sizes, seed: int) -> Dict:
+    return _init(z, prng_key(seed))
 
 
 # ---------------------------------------------------------------------------
@@ -122,11 +174,10 @@ def _rope(x, theta):
 
 
 @partial(jax.jit, static_argnums=(0, 4))
-def _layer(zk, p, x, eps_theta, quant):
-    z = dict(zk)
+def _layer(z: Sizes, p, x, eps_theta, quant):
     eps, theta = eps_theta
     B, S, D = x.shape
-    H, KVH, hd = z["H"], z["KVH"], z["hd"]
+    H, KVH, hd = z.H, z.KVH, z.hd
     h = _rms(x, p["ln1"], eps)
 
     def heads(t, n):
@@ -162,14 +213,52 @@ def _embed(embed, tokens):
     return embed[tokens].astype(jnp.float32) * math.sqrt(embed.shape[1])
 
 
-def forward(
-    z: Dict[str, int], params: Dict, tokens, eps: float, theta: float,
-    quant: Optional[str] = None,
-):
+def forward(z: Sizes, params: Dict, tokens, quant: Optional[str] = None):
     """Logits (B, S, V) in float32 for ``tokens`` (B, S)."""
-    zk = _shape_key(z)
     x = _embed(params["embed"], jnp.asarray(tokens, jnp.int32))
-    for i in range(z["L"]):
+    for i in range(z.L):
         p = jax.tree.map(lambda a: a[i], params["layers"])
-        x = _layer(zk, p, x, (float(eps), float(theta)), quant)
-    return _head(x, params["final_ln"], params["embed"], (quant, float(eps)))
+        x = _layer(z, p, x, (z.eps, z.theta), quant)
+    return _head(x, params["final_ln"], params["embed"], (quant, z.eps))
+
+
+# ---------------------------------------------------------------------------
+# Step counts
+# ---------------------------------------------------------------------------
+
+
+def layer_params(z: Sizes) -> int:
+    """Matmul parameters of one layer (norm weights are not matmuls)."""
+    attn = z.D * z.H * z.hd * 2 + z.D * z.KVH * z.hd * 2
+    return attn + 3 * z.D * z.F
+
+
+def weight_bytes(z: Sizes) -> int:
+    return (z.L * layer_params(z) + z.V * z.D) * BF16
+
+
+def forward_flops(z: Sizes, batch: int, seq: int) -> int:
+    """One causal forward over ``batch`` sequences of ``seq`` tokens,
+    logits at every position."""
+    tokens = batch * seq
+    mm = 2 * (z.L * layer_params(z) + z.V * z.D) * tokens
+    attn = z.L * 4 * batch * z.H * causal_pairs(seq, seq) * z.hd
+    return mm + attn
+
+
+def serve_tick(z: Sizes, lanes: Sequence[Tuple[int, int]]) -> Tuple[int, int]:
+    """(flops, bytes) one serving tick needs for its real tokens.
+
+    ``lanes`` holds ``(new_tokens, context)`` for each lane that carries
+    work: ``new_tokens`` enter at the end of a ``context``-long sequence
+    (their own positions included), and one row of logits is taken per
+    lane.  Bytes: every weight once, plus the live KV cache each lane
+    reads and the KV it writes."""
+    flops, kv = 0, 0
+    per_tok = 2 * z.L * layer_params(z)
+    kv_tok = z.L * 2 * z.KVH * z.hd * BF16
+    for n, ctx in lanes:
+        flops += per_tok * n + 2 * z.V * z.D
+        flops += z.L * 4 * z.H * causal_pairs(n, ctx) * z.hd
+        kv += ctx * kv_tok
+    return flops, weight_bytes(z) + kv

@@ -59,7 +59,7 @@ def main(argv=None) -> int:
     sched = st.sched
     knee = None
     for rate in [float(x) for x in args.rates.split(",")]:
-        arr = traffic.open_loop(r.traffic, rate, args.seconds, st.z["V"],
+        arr = traffic.open_loop(r.traffic, rate, args.seconds, st.z.V,
                                 args.seed)
         waiting = []
         for q in range(1, 5):

@@ -46,7 +46,7 @@ def packed_batch_fn(mix: Dict, vocab: int, seed: int):
     import jax
     import jax.numpy as jnp
 
-    from model_ref import prng_key
+    from harness import prng_key
 
     key = jax.random.fold_in(prng_key(seed), 1)
 

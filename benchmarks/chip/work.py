@@ -1,4 +1,5 @@
-"""Operations and bytes, computed from shapes, for kernels and model steps.
+"""Operations and bytes, computed from shapes, for kernels (the model
+steps' counts are each reference module's, under ``refs/``).
 
 Every count is what the algorithm needs, in the model's dtype (bf16, two
 bytes an element): a kernel that reads float32 copies, or computes the
@@ -70,61 +71,3 @@ def ideal_s(flops: float, nbytes: float, peak: Dict) -> float:
     """Least time the chip could take: the larger of the two bounds."""
     return max(flops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
 
-
-# ---------------------------------------------------------------------------
-# Model steps (dense decoder: attention + gated MLP, tied unembedding)
-# ---------------------------------------------------------------------------
-
-
-def sizes(conf: Dict) -> Dict[str, int]:
-    """The shape keys of a configuration file, under short names."""
-    d = conf["hidden_size"]
-    h = conf["num_attention_heads"]
-    return {
-        "L": conf["num_hidden_layers"],
-        "D": d,
-        "H": h,
-        "KVH": conf["num_key_value_heads"],
-        "hd": conf.get("head_dim") or d // h,
-        "F": conf["intermediate_size"],
-        "V": conf["vocab_size"],
-    }
-
-
-def layer_params(z: Dict[str, int]) -> int:
-    """Matmul parameters of one layer (norm weights are not matmuls)."""
-    attn = z["D"] * z["H"] * z["hd"] * 2 + z["D"] * z["KVH"] * z["hd"] * 2
-    return attn + 3 * z["D"] * z["F"]
-
-
-def weight_bytes(z: Dict[str, int]) -> int:
-    return (z["L"] * layer_params(z) + z["V"] * z["D"]) * BF16
-
-
-def forward_flops(z: Dict[str, int], batch: int, seq: int) -> int:
-    """One causal forward over ``batch`` sequences of ``seq`` tokens,
-    logits at every position."""
-    tokens = batch * seq
-    mm = 2 * (z["L"] * layer_params(z) + z["V"] * z["D"]) * tokens
-    attn = z["L"] * 4 * batch * z["H"] * causal_pairs(seq, seq) * z["hd"]
-    return mm + attn
-
-
-def serve_tick(
-    z: Dict[str, int], lanes: Sequence[Tuple[int, int]]
-) -> Tuple[int, int]:
-    """(flops, bytes) one serving tick needs for its real tokens.
-
-    ``lanes`` holds ``(new_tokens, context)`` for each lane that carries
-    work: ``new_tokens`` enter at the end of a ``context``-long sequence
-    (their own positions included), and one row of logits is taken per
-    lane.  Bytes: every weight once, plus the live KV cache each lane
-    reads and the KV it writes."""
-    flops, kv = 0, 0
-    per_tok = 2 * z["L"] * layer_params(z)
-    kv_tok = z["L"] * 2 * z["KVH"] * z["hd"] * BF16
-    for n, ctx in lanes:
-        flops += per_tok * n + 2 * z["V"] * z["D"]
-        flops += z["L"] * 4 * z["H"] * causal_pairs(n, ctx) * z["hd"]
-        kv += ctx * kv_tok
-    return flops, weight_bytes(z) + kv

@@ -9,6 +9,7 @@ import sys
 import types
 
 import numpy as np
+import pytest
 from benchlib import CHIP, ROOT, peak, small_conf
 
 import harness
@@ -40,7 +41,9 @@ def test_exits_nonzero_on_a_device_kind_without_peaks(monkeypatch, capsys):
     assert capsys.readouterr().out.strip() == ""
 
 
-def test_reference_matches_the_program_in_float32():
+@pytest.mark.parametrize(
+    "entry", harness.benchmark()["configs"], ids=lambda c: c["name"])
+def test_reference_matches_the_program_in_float32(entry):
     """The plain reference and the program's forward agree at a small size
     when both run in float32 at the highest precision."""
     import dataclasses
@@ -48,22 +51,22 @@ def test_reference_matches_the_program_in_float32():
     import jax
     import jax.numpy as jnp
 
-    import model_ref
     from repro.models.registry import build_model
-    from work import sizes
 
-    conf = small_conf()
+    conf = harness.load_json(ROOT / entry["file"])
+    ref = harness.reference(conf)
+    conf = ref.small(conf)
     cfg = dataclasses.replace(harness.program_config(conf), dtype="float32")
-    params = model_ref.make_params(sizes(conf), 3)
-    toks = np.random.default_rng(0).integers(0, 256, (2, 24))
+    z = ref.sizes(conf)
+    params = ref.make_params(z, 3)
+    toks = np.random.default_rng(0).integers(0, z.V, (2, 24))
     with jax.default_matmul_precision("highest"):
         got = build_model(cfg).forward(
             jax.tree.map(lambda a: a.astype(jnp.float32), params),
             tokens=jnp.asarray(toks),
         )
-    eps, theta = model_ref.norm_rope(conf)
-    ref = model_ref.forward(sizes(conf), params, toks, eps, theta)
-    err = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+    want = ref.forward(z, params, toks)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
     assert err < 1e-5
 
 
@@ -83,9 +86,11 @@ SERVE = {
 }
 
 
-def _run(workload, traffic_mix, seconds=1.5, seed=2**31 + 11, **settings):
+def _run(workload, traffic_mix, seconds=1.5, seed=2**31 + 11, conf=None,
+         **settings):
     """Drive a cell's job on the CPU: its workload file (or a workload
-    given as a dict), shrunk."""
+    given as a dict), shrunk, on ``conf`` (the cell's configuration
+    shrunk, where not given)."""
     if isinstance(workload, dict):
         workload, wl = "serve-chat", json.loads(json.dumps(workload))
     else:
@@ -94,7 +99,7 @@ def _run(workload, traffic_mix, seconds=1.5, seed=2**31 + 11, **settings):
     for key, value in settings.items():
         wl[key] = dict(wl[key], **value) if isinstance(value, dict) else value
     r = bench_run.Run(
-        name=workload, workload=wl, conf=small_conf(),
+        name=workload, workload=wl, conf=conf or small_conf(),
         traffic=traffic_mix, seed=seed, seconds=seconds, trace=False,
         peak=peak(), backend="pallas-interpret",
     )
@@ -120,6 +125,26 @@ def test_prefill_run_is_correct():
     assert bench_run.correct(r) and r.attempted > 0
     metrics = bench_run.read_metrics(harness.benchmark(), r)
     assert metrics["prefill_tok_s"]["value"] > 0 and "setup_s" in metrics
+
+
+def test_a_reference_module_outside_the_harness_runs_prefill_to_correct(
+        tmp_path, monkeypatch):
+    """A new architecture's module is a new file only: a copy of the dense
+    decoder's that reads the hidden width under another key, in a
+    directory of its own, drives the prefill job to correct; so no job or
+    harness code reads the dense decoder's keys."""
+    src = (CHIP / "refs" / "dense_decoder.py").read_text()
+    assert "hidden_size" in src
+    (tmp_path / "renamed_width.py").write_text(
+        src.replace("hidden_size", "model_width"))
+    conf = small_conf()
+    conf["model_width"] = conf.pop("hidden_size")
+    conf["reference"] = "renamed_width"
+    monkeypatch.setattr(harness, "REFS", tmp_path)
+    assert harness.reference(conf).__file__ == str(tmp_path / "renamed_width.py")
+    r = _run(*PREFILL, conf=conf)
+    assert bench_run.correct(r) and r.attempted > 0, r.checks
+    assert r.obs["step_flops"] > 0
 
 
 def test_prefill_answer_altered_is_not_correct(monkeypatch):

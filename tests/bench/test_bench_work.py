@@ -1,4 +1,5 @@
-"""Operations and bytes from shapes against hand counts at small sizes."""
+"""Kernels' operations and bytes from shapes against hand counts at small
+sizes (the model steps' counts are tested in test_bench_refs.py)."""
 
 import pytest
 from benchlib import CHIP  # noqa: F401
@@ -33,32 +34,6 @@ def test_kernel_work_hand_counts(result, operands, want):
 
 def test_unknown_kernel_is_none():
     assert work.kernel_work((5,), [(5,), (5,)]) is None
-
-
-SMALL = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
-         "num_key_value_heads": 1, "intermediate_size": 16, "vocab_size": 32}
-
-
-def test_forward_flops_hand_count():
-    z = work.sizes(SMALL)
-    # per layer: q 8x8, k 8x4, v 8x4, o 8x8, mlp 3 x 8x16 = 64+32+32+64+384
-    assert work.layer_params(z) == 576
-    tokens = 3 * 4
-    mm = 2 * (2 * 576 + 32 * 8) * tokens
-    attn = 2 * 4 * 3 * 2 * 10 * 4  # L * 4 * B * H * pairs(4, 4) * hd
-    assert work.forward_flops(z, 3, 4) == mm + attn
-    assert work.weight_bytes(z) == (2 * 576 + 256) * 2
-
-
-def test_serve_tick_counts_real_tokens_and_live_cache():
-    z = work.sizes(SMALL)
-    flops, nbytes = work.serve_tick(z, [(1, 10), (3, 3)])
-    per_tok = 2 * 2 * 576
-    want = per_tok * 4 + 2 * 2 * 32 * 8
-    want += 2 * 4 * 2 * (work.causal_pairs(1, 10) + work.causal_pairs(3, 3)) * 4
-    assert flops == want
-    kv_tok = 2 * 2 * 1 * 4 * 2
-    assert nbytes == work.weight_bytes(z) + 13 * kv_tok
 
 
 def test_ideal_takes_the_larger_bound():
