@@ -27,10 +27,11 @@ persists into ``TuningRecord.meta`` and the dispatch layer surfaces on
 from the recorded one.
 
 Workloads covered: ``dense_*`` (+fused epilogues), ``batch_matmul``,
-``sfm``; everything else falls back to the jnp structural lowering (see
-:class:`repro.backends.registry.PallasBackend`).  A fused flash-attention
-path (:func:`repro.kernels.flash_attention.flash_attention`) is exposed to
-the dispatch layer through ``PallasBackend.fused_attention``.
+``grouped_dense``, ``sfm``; everything else falls back to the jnp
+structural lowering (see :class:`repro.backends.registry.PallasBackend`).
+A fused flash-attention path
+(:func:`repro.kernels.flash_attention.flash_attention`) is exposed to the
+dispatch layer through ``PallasBackend.fused_attention``.
 """
 
 from __future__ import annotations
@@ -56,7 +57,7 @@ from ..kernels.softmax import DEFAULT_ROW_BLOCK
 # epilogue variant, incl. fused_dense which instantiates dense_bias_gelu;
 # attention_* covers the causal/window/softcap variants)
 _LOWERABLE_PREFIXES = ("dense_", "attention_")
-_LOWERABLE_NAMES = ("batch_matmul", "sfm")
+_LOWERABLE_NAMES = ("batch_matmul", "sfm", "grouped_dense")
 
 
 def supports(func: PrimFunc) -> bool:
@@ -157,7 +158,9 @@ def _check_grid(steps: int, blocks) -> None:
 class MatmulShape:
     """What a workload asks of :mod:`repro.kernels.matmul`: ``batch``
     products of (m, k) @ (k, n) in operands of ``itemsize`` bytes, with or
-    without a bias row."""
+    without a bias row; or, with ``groups``, of
+    :mod:`repro.kernels.grouped_matmul`: the m rows split into that many
+    groups, each times its own (k, n) weight."""
 
     batch: int
     m: int
@@ -165,6 +168,7 @@ class MatmulShape:
     k: int
     itemsize: int
     bias: bool = False
+    groups: int = 0
 
     @property
     def dims(self) -> Tuple[int, int, int]:
@@ -176,8 +180,10 @@ class MatmulShape:
         return (sublane_tile(self.itemsize), LANE, LANE)
 
     def grid_steps(self, blocks) -> int:
+        """Grid steps of the kernel (most, where the groups decide)."""
         bm, bn, bk = blocks
-        return self.batch * (self.m // bm) * (self.n // bn) * (self.k // bk)
+        rows = self.m // bm + max(self.groups - 1, 0)
+        return self.batch * rows * (self.n // bn) * (self.k // bk)
 
     def vmem_bytes(self, blocks) -> int:
         return block_vmem_bytes(*blocks, self.itemsize, bias=self.bias)
@@ -214,17 +220,23 @@ def _legal_blocks(shape: MatmulShape) -> Tuple[Tuple[int, int, int], ...]:
 
 def matmul_shape(func: PrimFunc) -> Optional[MatmulShape]:
     """The matmul-kernel problem of a workload that lowers onto
-    :mod:`repro.kernels.matmul` (``dense_*``, ``batch_matmul``), else
+    :mod:`repro.kernels.matmul` (``dense_*``, ``batch_matmul``) or
+    :mod:`repro.kernels.grouped_matmul` (``grouped_dense``), else
     ``None``."""
+    groups = 0
     if func.name.startswith("dense_"):
         (m, k), n, batch = func.inputs[0].shape, func.inputs[1].shape[1], 1
     elif func.name == "batch_matmul":
         (batch, m, k), n = func.inputs[0].shape, func.inputs[1].shape[2]
+    elif func.name == "grouped_dense":
+        (m, k), (groups, _, n), batch = (
+            func.inputs[0].shape, func.inputs[1].shape, 1
+        )
     else:
         return None
     return MatmulShape(
         batch, m, n, k, jnp.dtype(func.inputs[0].dtype).itemsize,
-        bias=any(b.name == "bias" for b in func.inputs),
+        bias=any(b.name == "bias" for b in func.inputs), groups=groups,
     )
 
 
@@ -289,6 +301,33 @@ def lower_batch_matmul(
         out = mm.batch_matmul(
             inputs["A"], inputs["B"], block_sizes=blocks, interpret=interpret,
             task=task,
+        )
+        return {func.outputs[0].name: out}
+
+    return fn, meta
+
+
+def lower_grouped_dense(
+    sch: Schedule, *, interpret: bool, task: str = ""
+) -> Tuple[Callable, Dict[str, Any]]:
+    """Tuned grouped matmul via the Pallas grouped kernel.  The group
+    sizes are ``SIZES`` where the caller has them (dispatch), else the
+    column sums of the workload's row-membership ``MASK`` (search)."""
+    from ..kernels.grouped_matmul import grouped_matmul
+
+    func = sch.func
+    shape = matmul_shape(func)
+    sampled, blocks = matmul_blocks(sch, shape)
+    _check_grid(shape.grid_steps(blocks), blocks)
+    meta = _block_meta("grouped_matmul", sampled, blocks)
+
+    def fn(inputs: Dict):
+        sizes = inputs.get("SIZES")
+        if sizes is None:
+            sizes = jnp.sum(inputs["MASK"].astype(jnp.int32), axis=0)
+        out = grouped_matmul(
+            inputs["X"], inputs["W"], sizes, block_sizes=blocks,
+            interpret=interpret, task=task,
         )
         return {func.outputs[0].name: out}
 
@@ -491,6 +530,8 @@ def lower_to_pallas(
         return lower_attention(sch, **kw)
     if name == "batch_matmul":
         return lower_batch_matmul(sch, **kw)
+    if name == "grouped_dense":
+        return lower_grouped_dense(sch, **kw)
     if name == "sfm":
         return lower_sfm(sch, **kw)
     raise ValueError(f"no Pallas lowering for workload {name!r}")

@@ -28,16 +28,25 @@ class ModelConfig:
     attn_softcap: Optional[float] = None
     logit_softcap: Optional[float] = None
     local_window: int = 0            # >0: sliding-window attention size
-    alt_local_global: bool = False   # gemma2: alternate local/global layers
+    global_every: int = 0            # >0: every Nth layer global, rest local
+    nope_global: bool = False        # no rotary on global layers
+    qk_norm: bool = False            # RMSNorm of q and k per head
+    attn_gate: bool = False          # attention output times sigmoid(x W_g)
     rope_theta: float = 10000.0
     mrope: bool = False              # qwen2-vl M-RoPE
     post_norms: bool = False         # gemma2 sandwich norms
     act: str = "silu"
+    tie_embed: bool = True           # False: an output head of its own
     # MoE
     moe_experts: int = 0
     moe_top_k: int = 0
     moe_dense_residual: bool = False  # arctic: dense MLP in parallel
     moe_capacity_factor: float = 2.0  # <=0: dropless (exact)
+    moe_d_ff: int = 0                # expert width; 0: d_ff
+    moe_shared_experts: int = 0      # shared experts of width moe_d_ff
+    moe_router: str = "softmax"      # softmax | sigmoid (+ selection bias)
+    moe_route_scale: float = 1.0     # sigmoid router: scale of the weights
+    first_dense_layers: int = 0      # leading dense layers (width d_ff)
     # SSM (mamba2 / hymba)
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -66,6 +75,10 @@ class ModelConfig:
             return True
         return False
 
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
     def params_count(self) -> int:
         """Approximate parameter count (embeddings + layers)."""
         D, F, V = self.d_model, self.d_ff, self.vocab
@@ -73,16 +86,24 @@ class ModelConfig:
         per_layer = 0
         if H:
             per_layer += D * (H * hd) + 2 * D * (KVH * hd) + (H * hd) * D
+            if self.attn_gate:
+                per_layer += D * (H * hd)
         if self.ssm_state:
             inner = self.ssm_heads * self.ssm_head_dim
             per_layer += 2 * D * inner + 2 * D * self.ssm_state + inner * D
+        n_dense = self.first_dense_layers
+        total = n_dense * (per_layer + 3 * D * F)
         if self.moe_experts:
-            per_layer += self.moe_experts * 3 * D * F + D * self.moe_experts
+            Fe = self.expert_ff
+            per_layer += self.moe_experts * 3 * D * Fe + D * self.moe_experts
+            per_layer += self.moe_shared_experts * 3 * D * Fe
             if self.moe_dense_residual:
                 per_layer += 3 * D * F
         elif F:
             per_layer += 3 * D * F
-        total = self.n_layers * per_layer + V * D
+        total += (self.n_layers - n_dense) * per_layer + V * D
+        if not self.tie_embed:
+            total += V * D
         if self.enc_layers:
             total += self.enc_layers * (4 * D * D + 3 * D * F)
             total += self.n_layers * (4 * D * D)  # cross attention
@@ -91,14 +112,10 @@ class ModelConfig:
     def active_params_count(self) -> int:
         if not self.moe_experts:
             return self.params_count()
-        D, F = self.d_model, self.d_ff
-        per_layer_moe = self.moe_experts * 3 * D * F
-        active_moe = self.moe_top_k * 3 * D * F
-        return (
-            self.params_count()
-            - self.n_layers * per_layer_moe
-            + self.n_layers * active_moe
-        )
+        D, Fe = self.d_model, self.expert_ff
+        n_moe = self.n_layers - self.first_dense_layers
+        idle = (self.moe_experts - self.moe_top_k) * 3 * D * Fe
+        return self.params_count() - n_moe * idle
 
 
 @dataclass(frozen=True)

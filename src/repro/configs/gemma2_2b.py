@@ -9,7 +9,7 @@ CONFIG = ModelConfig(
     n_layers=26, d_model=2304, n_heads=8, n_kv_heads=4, d_ff=9216,
     vocab=256000, head_dim=256,
     attn_softcap=50.0, logit_softcap=30.0,
-    local_window=4096, alt_local_global=True, post_norms=True,
+    local_window=4096, global_every=2, post_norms=True,
     act="gelu",
 )
 

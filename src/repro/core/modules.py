@@ -128,6 +128,8 @@ class MultiLevelTiling(Module):
             return False
         if bn.annotations.get("tensorize"):
             return False  # already handled by a hardware module
+        if _grouped(sch):
+            return False  # the grouped kernel's block is UseMXU's alone
         # needs enough arithmetic intensity to be worth tiling
         return _is_matmul_pattern(blk) or len(blk.reduce_axes) >= 1
 
@@ -202,6 +204,14 @@ class MultiLevelTiling(Module):
             sch.vectorize(fresh[-1])
 
 
+def _grouped(sch: Schedule) -> bool:
+    """Is the program a grouped matmul (its rows' groups are data)?"""
+    from ..backends.pallas_backend import matmul_shape
+
+    shape = matmul_shape(sch.func)
+    return shape is not None and shape.groups > 0
+
+
 # ---------------------------------------------------------------------------
 # UseMXU — the hardware-specific module (paper §6.3, Use-Tensor-Core)
 # ---------------------------------------------------------------------------
@@ -234,7 +244,9 @@ class UseMXU(MultiLevelTiling):
         blk = bn.block
         if bn.attached or bn.annotations.get("tensorize"):
             return False
-        return _is_matmul_pattern(blk)
+        return _is_matmul_pattern(blk) or (
+            self._kernel_shape(sch, block) is not None
+        )
 
     def apply(self, sch: Schedule, block: BlockRV) -> None:
         if self.stage_vmem and self._kernel_shape(sch, block) is None:
@@ -260,7 +272,10 @@ class UseMXU(MultiLevelTiling):
 
         shape = matmul_shape(sch.func)
         blk = sch.block_info(block).block
-        if shape is None or len(blk.reduce_axes) != 1:
+        # the grouped kernel's block reduces over (k, groups): the groups
+        # are walked by the kernel's grid, not blocked
+        n_red = 2 if shape is not None and shape.groups else 1
+        if shape is None or len(blk.reduce_axes) != n_red:
             return None
         ext = [a.extent for a in blk.spatial_axes] + [blk.reduce_axes[0].extent]
         if ext[-3:] != list(shape.dims) or not shape.legal_blocks():
@@ -271,15 +286,21 @@ class UseMXU(MultiLevelTiling):
         shape = self._kernel_shape(sch, block)
         if shape is None:
             return super()._split(sch, block, s_loops, r_loops, n_s, n_r)
-        f = sch.sample_mxu_block(s_loops + r_loops, shape.itemsize, shape.bias)
+        blocked = s_loops + r_loops[:1]
+        f = sch.sample_mxu_block(
+            blocked, shape.itemsize, shape.bias, groups=shape.groups
+        )
         outer, inner = f[0::2], f[1::2]
         splits = [
             sch.split(l, [o] + [1] * (n - 2) + [b])
             for l, o, b, n in zip(
-                s_loops + r_loops, outer, inner,
-                [n_s] * len(s_loops) + [n_r] * len(r_loops),
+                blocked, outer, inner,
+                [n_s] * len(s_loops) + [n_r],
             )
         ]
+        for l in r_loops[1:]:  # the group loop: whole, outside the block
+            ext = sch.loop_info(l).extent
+            splits.append(sch.split(l, [ext] + [1] * (n_r - 1)))
         return splits[: len(s_loops)], splits[len(s_loops):]
 
 

@@ -85,7 +85,8 @@ def _mutate_mxu_block(trace: Trace, idx: int, rng) -> Optional[Trace]:
     it = trace.insts[idx]
     batch, m, n, k = it.attrs["shape"]
     legal = MatmulShape(
-        batch, m, n, k, it.attrs["itemsize"], it.attrs.get("bias", False)
+        batch, m, n, k, it.attrs["itemsize"], it.attrs.get("bias", False),
+        it.attrs.get("groups", 0),
     ).legal_blocks()
     dec = tuple(it.decision)
     for axis in rng.permutation(3):

@@ -300,11 +300,14 @@ class Schedule:
         itemsize: int,
         bias: bool = False,
         decision: Optional[List[int]] = None,
+        groups: int = 0,
     ) -> List[ExprRV]:
         """Sample the matmul kernel's block (bm, bn, bk) for a block whose
         ``loops`` are (batch..., i, j, k): one draw from the kernel's legal
         set (:meth:`repro.backends.pallas_backend.MatmulShape.legal_blocks`),
         bm first, then bn among the blocks that keep bm, then bk.
+        ``groups`` > 0 samples the grouped kernel's block for rows split
+        into that many groups.
 
         Returns (outer extent, block extent) for every loop — block 1 for
         batch loops — as the factors that split each loop into the
@@ -314,7 +317,8 @@ class Schedule:
 
         ext = [self._find_loop(l.var)[0].extent for l in loops]
         shape = MatmulShape(
-            int(np.prod(ext[:-3])), *ext[-3:], int(itemsize), bool(bias)
+            int(np.prod(ext[:-3])), *ext[-3:], int(itemsize), bool(bias),
+            int(groups),
         )
         legal = shape.legal_blocks()
         if decision is None:
@@ -332,11 +336,14 @@ class Schedule:
         rvs = []
         for e, b in zip(ext, blocks):
             rvs += [new_expr_rv(e // b), new_expr_rv(b)]
+        attrs = {"shape": [shape.batch, *shape.dims],
+                 "itemsize": shape.itemsize, "bias": shape.bias}
+        if shape.groups:
+            attrs["groups"] = shape.groups
         self._record(
             "sample_mxu_block",
             list(loops),
-            {"shape": [shape.batch, *shape.dims], "itemsize": shape.itemsize,
-             "bias": shape.bias},
+            attrs,
             rvs,
             decision=list(map(int, decision)),
         )

@@ -291,7 +291,7 @@ def _execute(sch, name: str, inputs: List, attrs: Dict, decision) -> List[RV]:
     if name == "sample_mxu_block":
         return sch.sample_mxu_block(
             inputs, attrs["itemsize"], attrs.get("bias", False),
-            decision=decision,
+            decision=decision, groups=attrs.get("groups", 0),
         )
     if name == "sample_categorical":
         return [

@@ -70,7 +70,9 @@ def validate_schedule(sch: Schedule) -> ValidationResult:
 
     shape = matmul_shape(sch.func)
     mxu = find_tensorized_block(sch)
-    if shape is not None and mxu.annotations.get("tensorize") == "mxu":
+    if shape is not None and (
+        shape.groups or mxu.annotations.get("tensorize") == "mxu"
+    ):
         _, blocks = matmul_blocks(sch, shape)
         steps = shape.grid_steps(blocks)
         tile = int(np.prod(blocks))

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Tuple
 
+import numpy as np
+
 from .tir import (
     Axis,
     BinOp,
@@ -31,6 +33,7 @@ from .tir import (
     const,
     load,
     mul,
+    random_inputs,
 )
 
 WORKLOADS: Dict[str, Callable[..., PrimFunc]] = {}
@@ -172,6 +175,70 @@ def batch_matmul(
         reduce_op="add",
     )
     return PrimFunc("batch_matmul", (A, B), (C,), (blk,))
+
+
+@register("grouped_dense")
+def grouped_dense(
+    m: int = 512, g: int = 8, k: int = 128, n: int = 128,
+    dtype: str = "bfloat16",
+) -> PrimFunc:
+    """Grouped matmul: Y[i, j] = sum_kk X[i, kk] * W[group(i), kk, j] —
+    the dropless expert layer's up/gate and down projections over the
+    (token, slot) rows sorted by expert.
+
+    Which group a row belongs to is data, not an affine index, so the
+    program takes it as an input: ``MASK[i, e]`` is 1 where row ``i``
+    belongs to group ``e``, else 0, and the block reduces over groups as
+    well as ``k``.  The rows of one group are contiguous and the groups
+    in order, so ``MASK`` is equivalent to the group sizes (its column
+    sums), which is what the Pallas kernel takes
+    (:func:`repro.kernels.grouped_matmul.grouped_matmul`) and what varies
+    from call to call.  Axes are ordered (i, j, kk, e): the matmul
+    kernel's (m, n, k) lead, as the MXU block sampler reads them.
+    """
+    X = Buffer("X", (m, k), dtype)
+    W = Buffer("W", (g, k, n), dtype)
+    MASK = Buffer("MASK", (m, g), dtype)
+    Y = Buffer("Y", (m, n), dtype)
+    blk = Block(
+        name="grouped",
+        axes=(
+            Axis("i", m), Axis("j", n), Axis("kk", k, REDUCE),
+            Axis("e", g, REDUCE),
+        ),
+        expr=mul(
+            mul(load(MASK, "i", "e"), load(X, "i", "kk")),
+            load(W, "e", "kk", "j"),
+        ),
+        write=Y,
+        write_indices=(_v("i"), _v("j")),
+        reduce_op="add",
+    )
+    return PrimFunc("grouped_dense", (X, W, MASK), (Y,), (blk,))
+
+
+def group_sizes(m: int, g: int, seed: int = 0) -> np.ndarray:
+    """Seeded, uneven sizes of ``g`` groups of ``m`` rows in all, as a
+    router leaves them: multinomial over Dirichlet shares, so some groups
+    are several times the mean and some may be empty."""
+    rng = np.random.default_rng([seed, g, m])
+    return rng.multinomial(m, rng.dirichlet(np.full(g, 2.0))).astype(np.int32)
+
+
+def membership(sizes: np.ndarray, dtype: str = "float32") -> np.ndarray:
+    """(m, g) 0/1 mask of rows in contiguous groups of ``sizes`` rows."""
+    gid = np.repeat(np.arange(len(sizes)), sizes)
+    return (gid[:, None] == np.arange(len(sizes))[None, :]).astype(np.dtype(dtype))
+
+
+def workload_inputs(func: PrimFunc, seed: int = 0) -> Dict[str, np.ndarray]:
+    """Inputs a candidate is measured on: seeded random data, except that
+    a grouped matmul's ``MASK`` holds seeded, uneven groups."""
+    ins = random_inputs(func, seed)
+    if func.name == "grouped_dense":
+        m, g = func.inputs[2].shape
+        ins["MASK"] = membership(group_sizes(m, g, seed), func.inputs[2].dtype)
+    return ins
 
 
 @register("tbg")
@@ -1060,4 +1127,5 @@ REDUCED_KWARGS: Dict[str, Dict] = {
     "rmsnorm": dict(tokens=16, d=32),
     "attention": dict(b=1, h=2, kvh=1, s=16, d=8),
     "attention_decode": dict(b=2, h=2, kvh=1, t=16, d=8),
+    "grouped_dense": dict(m=64, g=4, k=32, n=32),
 }

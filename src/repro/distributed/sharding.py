@@ -277,7 +277,7 @@ def param_shardings(mesh: Mesh, params: Any, fsdp: bool = True) -> Any:
     def rec(tree, path: str):
         if isinstance(tree, dict):
             return {k: rec(v, f"{path}/{k}" if path else k) for k, v in tree.items()}
-        scanned = path.startswith("layers") or path.startswith("enc_layers")
+        scanned = path.startswith(("layers", "dense_layers", "enc_layers"))
         axes = leaf_axes(path, tree)
         ndim = len(tree.shape)
         want = ndim - (1 if scanned else 0)

@@ -202,7 +202,7 @@ class DispatchContext:
             name, _ = parse_workload_key(key)
             mxu = self.use_mxu and name in (
                 "dense", "batch_matmul", "gmm", "attention",
-                "attention_decode",
+                "attention_decode", "grouped_dense",
             )
         space = SpaceGenerator(default_modules(use_mxu=mxu))
         sch = first_valid_schedule(func, space, self.default_seed_scan)
@@ -396,6 +396,42 @@ class DispatchContext:
         b2 = b.reshape(B, K, N).astype(jnp.float32)
         out = kern.grad_fn(a2, b2)
         return out.reshape(*bdims, M, N)
+
+    def grouped_dense(
+        self, x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray
+    ) -> Optional[jnp.ndarray]:
+        """Tuned grouped matmul; x: (m, k) rows sorted by group, w:
+        (g, k, n), group_sizes: (g,) summing to m.  Keyed by (m, g, k, n)
+        and the operands' dtype, which the kernel reads as they are
+        (float32 accumulation, output in x's dtype).  The sizes go to the
+        kernel as they are: the workload's row-membership ``MASK`` is for
+        search and measurement only.  None -> caller falls back to
+        ``jax.lax.ragged_dot``."""
+        if x.ndim != 2 or w.ndim != 3 or x.shape[1] != w.shape[1]:
+            self._note("fallback", None, "grouped_dense", "shape_mismatch")
+            return None
+        if x.dtype != w.dtype or group_sizes.shape != (w.shape[0],):
+            self._note("fallback", None, "grouped_dense", "shape_mismatch")
+            return None
+        (m, k), (g, _, n) = x.shape, w.shape
+        key = workload_key(
+            "grouped_dense", m=int(m), g=int(g), k=int(k), n=int(n),
+            dtype=x.dtype.name,
+        )
+        kern = self._lookup(key, "grouped_dense")
+        if kern is None:
+            return None
+        if kern.grad_fn is None:
+            def ref(x2, w2, sizes):
+                return jax.lax.ragged_dot(
+                    x2, w2, sizes, preferred_element_type=jnp.float32
+                ).astype(x2.dtype)
+
+            def fwd_kernel(x2, w2, sizes):
+                return kern.fn({"X": x2, "W": w2, "SIZES": sizes})[kern.out_name]
+
+            kern.grad_fn = _with_reference_grad(fwd_kernel, ref)
+        return kern.grad_fn(x, w, group_sizes.astype(jnp.int32))
 
     def attention(
         self,

@@ -12,6 +12,8 @@ registered tensor-program workloads in :mod:`repro.core.workloads`:
   norms lower to exactly one ``rsqrt`` each;
 * ``exp``          -> ``sfm`` (row softmax) over the flattened operand —
   the attention-softmax sites;
+* ``ragged_dot_general`` -> ``grouped_dense`` (rows, groups, k, n, dtype)
+  — the dropless expert layer's grouped matmuls;
 * anything else    -> skipped.
 
 ``scan`` bodies multiply site occurrence counts by the trip count, so a
@@ -41,7 +43,9 @@ from ..search.task_scheduler import TuneTask
 TOKEN_TILE = 128  # default representative token block (batch=1 x seq=128)
 
 # ops the extractor understands; everything else is skipped
-EXTRACTABLE_OPS = ("dense", "batch_matmul", "rmsnorm", "sfm", "attention")
+EXTRACTABLE_OPS = (
+    "dense", "batch_matmul", "rmsnorm", "sfm", "attention", "grouped_dense",
+)
 
 # ops extracted from the decode trace (serving): dense/bmm keyed on
 # m = batch, plus the single-token cache-attention workload.  sfm is
@@ -94,6 +98,7 @@ class ExtractedTask:
         func = get_workload(self.op, **self.kwargs)
         mxu = use_mxu and self.op in (
             "dense", "batch_matmul", "attention", "attention_decode",
+            "grouped_dense",
         )
         return TuneTask(key=self.key, func=func, weight=self.weight, use_mxu=mxu)
 
@@ -381,6 +386,29 @@ def _dot_site(eqn) -> Optional[TaskSite]:
     return TaskSite("dense", dict(m=m, n=n, k=k), 1.0, dispatchable=disp)
 
 
+def _ragged_site(eqn) -> Optional[TaskSite]:
+    """A grouped matmul: lhs rows grouped by runtime sizes, one rhs matrix
+    per group.  The grouped dispatch hook serves ``x (m, k)`` against
+    ``w (g, k, n)`` with the groups along x's rows."""
+    dn = eqn.params["ragged_dot_dimension_numbers"]
+    lhs = eqn.invars[0].aval
+    rhs = eqn.invars[1].aval
+    if len(lhs.shape) != 2 or len(rhs.shape) != 3:
+        return None
+    disp = (
+        tuple(dn.lhs_ragged_dimensions) == (0,)
+        and tuple(dn.rhs_group_dimensions) == (0,)
+        and dn.dot_dimension_numbers == (((1,), (1,)), ((), ()))
+    )
+    (m, k), (g, _, n) = lhs.shape, rhs.shape
+    return TaskSite(
+        "grouped_dense",
+        dict(m=int(m), g=int(g), k=int(k), n=int(n), dtype=lhs.dtype.name),
+        1.0,
+        dispatchable=disp,
+    )
+
+
 def _rsqrt_site(eqn, d_model: int, eps: float) -> Optional[TaskSite]:
     if d_model <= 0:
         return None
@@ -416,6 +444,8 @@ def sites_from_jaxpr(
             site = _rsqrt_site(eqn, d_model, norm_eps)
         elif name == "exp":
             site = _exp_site(eqn)
+        elif name == "ragged_dot_general":
+            site = _ragged_site(eqn)
         if site is not None:
             site.count = float(mult)
             sites.append(site)
@@ -444,6 +474,9 @@ def _task_flops(op: str, kw: Dict[str, Any]) -> int:
     if op == "attention_decode":
         # one query token against a length-t cache
         return 4 * kw["b"] * kw["h"] * kw["t"] * kw["d"]
+    if op == "grouped_dense":
+        # every row against its own group's weight
+        return 2 * kw["m"] * kw["n"] * kw["k"]
     return 0
 
 
@@ -603,6 +636,12 @@ def extract_task_specs(
         sites = [s for s in sites if s.dispatchable]
     sites = shard_sites(sites, _resolve_mesh(mesh))
     tasks = dedup_sites(sites, min_task_elems=min_task_elems)
+    if trace_enabled():
+        for t in tasks:
+            if t.op == "grouped_dense":
+                kw = t.kwargs
+                emit("extract.grouped", rows=kw["m"], groups=kw["g"],
+                     k=kw["k"], n=kw["n"], layers=t.weight)
     return _apply_max_tasks(cfg, tasks, max_tasks, ops, "attention")
 
 

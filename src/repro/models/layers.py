@@ -83,6 +83,29 @@ def bmm_op(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
         "...mk,...kn->...mn", a, b, preferred_element_type=jnp.float32
     )
 
+
+def grouped_op(
+    x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray
+) -> jnp.ndarray:
+    """Grouped matmul — the grouped-expert dispatch point.
+
+    x: (m, k) rows sorted by group, w: (g, k, n), group_sizes: (g,) int32
+    summing to m; row block e (``group_sizes[e]`` rows, in order) is
+    multiplied by ``w[e]``.  Returns (m, n) in x's dtype, accumulated in
+    float32.  Under an active DispatchContext with a tuned
+    ``grouped_dense`` record for (m, g, k, n, dtype) the tuned kernel
+    runs; otherwise ``jax.lax.ragged_dot``, XLA's own grouped matmul.
+    """
+    ctx = _dispatch_ctx()
+    if ctx is not None:
+        out = ctx.grouped_dense(x, w, group_sizes)
+        if out is not None:
+            return out
+    return jax.lax.ragged_dot(
+        x, w, group_sizes, preferred_element_type=jnp.float32
+    ).astype(x.dtype)
+
+
 # logical-axis registry: path-pattern -> axes tuple, filled by init fns.
 # (simpler than threading metadata through every pytree leaf)
 PARAM_AXES: Dict[str, Tuple[Optional[str], ...]] = {}
@@ -372,6 +395,13 @@ def attention_init(rng, cfg, prefix: str) -> Dict:
     reg_axes(f"{prefix}/wk", ("embed", "heads"))
     reg_axes(f"{prefix}/wv", ("embed", "heads"))
     reg_axes(f"{prefix}/wo", ("heads", "embed"))
+    if cfg.attn_gate:
+        p["wg"] = _init(jax.random.fold_in(rng, 4), (D, H * hd))
+        reg_axes(f"{prefix}/wg", ("embed", "heads"))
+    if cfg.qk_norm:
+        for name in ("q_norm", "k_norm"):
+            p[name] = jnp.ones((hd,), dtype=jnp.float32)
+            reg_axes(f"{prefix}/{name}", (None,))
     if cfg.qkv_bias:
         p["bq"] = jnp.zeros((H * hd,), dtype=jnp.float32)
         p["bk"] = jnp.zeros((KVH * hd,), dtype=jnp.float32)
@@ -428,8 +458,8 @@ def mlp(p: Dict, x: jnp.ndarray, act: str = "silu") -> jnp.ndarray:
 
 
 def moe_init(rng, cfg, prefix: str) -> Dict:
-    D, E, F = cfg.d_model, cfg.moe_experts, cfg.d_ff
-    ks = jax.random.split(rng, 4)
+    D, E, F = cfg.d_model, cfg.moe_experts, cfg.expert_ff
+    ks = jax.random.split(rng, 5)
     p = {
         "router": _init(ks[0], (D, E), dtype=jnp.float32),
         "wi": _init(ks[1], (E, D, F)),
@@ -440,36 +470,110 @@ def moe_init(rng, cfg, prefix: str) -> Dict:
     reg_axes(f"{prefix}/wi", ("experts", "embed", None))
     reg_axes(f"{prefix}/wg", ("experts", "embed", None))
     reg_axes(f"{prefix}/wo", ("experts", None, "embed"))
+    if cfg.moe_router == "sigmoid":
+        p["expert_bias"] = jnp.zeros((E,), dtype=jnp.float32)
+        reg_axes(f"{prefix}/expert_bias", (None,))
+    if cfg.moe_shared_experts:
+        p["shared"] = mlp_init(
+            ks[4], D, F * cfg.moe_shared_experts, f"{prefix}/shared"
+        )
     return p
 
 
-def moe(
-    p: Dict,
-    x: jnp.ndarray,
-    top_k: int,
-    capacity_factor: float = 2.0,
-    act: str = "silu",
-) -> jnp.ndarray:
-    """Capacity-bounded top-k MoE with scatter dispatch (GShard-style).
+_ACT = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}
+
+
+def moe_route(
+    p: Dict, xt: jnp.ndarray, top_k: int, router: str = "softmax",
+    scale: float = 1.0,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Expert ids (T, k) and float32 weights (T, k) of tokens ``xt`` (T, D).
+
+    ``softmax``: top-k of the softmax, renormalised.  ``sigmoid``: scores
+    ``s = sigmoid(x W_r)`` in float32; experts are chosen by top-k of
+    ``s + expert_bias`` (the bias steers selection only), weighted by
+    their own scores normalised to sum 1 and times ``scale``.
+    """
+    if router == "sigmoid":
+        logits = jnp.einsum(
+            "td,de->te", xt.astype(jnp.float32), p["router"],
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + p["expert_bias"], top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, top_k)
+    return idx, w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+
+
+def moe(p: Dict, x: jnp.ndarray, cfg) -> jnp.ndarray:
+    """Top-k mixture of experts (plus the shared experts, if any).
+
+    ``cfg.moe_capacity_factor <= 0`` is dropless: every (token, slot)
+    pair is computed, by grouped matmuls over the pairs sorted by expert
+    (:func:`_moe_grouped`).  Otherwise, and on a device mesh, tokens go
+    through the capacity-bounded scatter (:func:`_moe_capacity`), whose
+    scatter/gather pair becomes the all-to-all under expert parallelism.
+    """
+    B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    with jax.named_scope("moe.route"):
+        idx, w = moe_route(
+            p, xt, cfg.moe_top_k, cfg.moe_router, cfg.moe_route_scale
+        )
+    from ..distributed import sharding as _shd
+
+    if cfg.moe_capacity_factor <= 0 and _shd.get_mesh() is None:
+        out = _moe_grouped(p, xt, idx, w, _ACT[cfg.act])
+    else:
+        out = _moe_capacity(p, xt, idx, w, cfg.moe_capacity_factor, _ACT[cfg.act])
+    if "shared" in p:
+        out = out + mlp(p["shared"], x, cfg.act).reshape(B * S, D)
+    return out.reshape(B, S, D).astype(x.dtype)
+
+
+def _moe_grouped(p: Dict, xt, idx, w, actf) -> jnp.ndarray:
+    """Dropless experts: the T*k (token, slot) rows sorted by expert, the
+    experts' up/gate and down projections as grouped matmuls over them
+    (:func:`grouped_op`), then unsorted and weighted per token (float32
+    combine)."""
+    T, D = xt.shape
+    k = idx.shape[1]
+    E = p["wi"].shape[0]
+    with jax.named_scope("moe.permute"):
+        eid = idx.reshape(T * k)
+        order = jnp.argsort(eid, stable=True)
+        rows = xt[order // k]
+        sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        h = grouped_op(rows, p["wi"], sizes)
+        g = grouped_op(rows, p["wg"], sizes)
+        y = grouped_op(actf(g) * h, p["wo"], sizes)
+    with jax.named_scope("moe.combine"):
+        slot = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype)
+        )
+        y = y[slot].reshape(T, k, D).astype(jnp.float32)
+        return jnp.sum(y * w[..., None], axis=1)
+
+
+def _moe_capacity(p: Dict, xt, gate_idx, gate_vals, capacity_factor, actf):
+    """Capacity-bounded top-k experts with scatter dispatch (GShard-style).
 
     Tokens are routed to experts through a position-in-expert cumsum and a
     scatter into an (E, C, D) buffer — the scatter/gather pair becomes the
     all-to-all under expert-parallel sharding.  Overflow tokens are dropped
     (their contribution is zero), standard for capacity-based MoE.
-    ``capacity_factor <= 0`` selects the dropless upper bound C = T (exact
-    but memory-heavier; used by correctness tests and small decode batches).
+    ``capacity_factor <= 0`` (dropless, on a mesh) takes C = T.
     """
-    B, S, D = x.shape
+    T, D = xt.shape
+    top_k = gate_idx.shape[1]
     E = p["router"].shape[1]
-    T = B * S
-    xt = x.reshape(T, D)
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # (T, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
     if capacity_factor <= 0:
-        C = T  # dropless
+        C = T
     else:
         C = max(int(capacity_factor * top_k * T / E), 4)
     # position of each (token, k) slot within its expert queue
@@ -481,7 +585,7 @@ def moe(
     keep = pos < C
     # scatter tokens into (E, C, D); dropped tokens get an out-of-bounds
     # expert id so mode="drop" skips them (never clobber a live slot)
-    buf = jnp.zeros((E, C, D), dtype=x.dtype)
+    buf = jnp.zeros((E, C, D), dtype=xt.dtype)
     tok_idx = jnp.repeat(jnp.arange(T), top_k)
     src = xt[tok_idx]  # (T*k, D)
     e_sc = jnp.where(keep, eid, E)       # E = out of bounds -> dropped
@@ -499,7 +603,6 @@ def moe(
     # cast back to the activation dtype as before)
     h = bmm_op(buf, p["wi"]).astype(buf.dtype)
     g = bmm_op(buf, p["wg"]).astype(buf.dtype)
-    actf = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[act]
     h = actf(g) * h
     out_e = _shd.shard(
         bmm_op(h, p["wo"]).astype(buf.dtype), "experts"
@@ -512,8 +615,7 @@ def moe(
     # the EP-combine all-reduce is internal to the gather lowering — so keep
     # the numerically safer accumulate)
     out = jnp.zeros((T, D), dtype=jnp.float32)
-    out = _shd.shard(out.at[tok_idx].add(gathered.astype(jnp.float32)), "tokens")
-    return out.reshape(B, S, D).astype(x.dtype)
+    return _shd.shard(out.at[tok_idx].add(gathered.astype(jnp.float32)), "tokens")
 
 
 # ---------------------------------------------------------------------------

@@ -9,19 +9,24 @@ One scanned-layer decoder implementation parameterized by
 * Mamba-2 SSD (attention-free),
 * Hymba (parallel attention + SSD heads, sliding window),
 * Qwen2-VL backbone (M-RoPE, embedding inputs),
-* Whisper (encoder stack + cross-attention decoder).
+* Whisper (encoder stack + cross-attention decoder),
+* Trinity (afmoe: leading dense layers, then sparse experts with a
+  shared expert, under a 3:1 window/full attention pattern).
 
 Layers are stacked along a leading L axis and executed with
 ``jax.lax.scan`` so the lowered HLO is O(1) in depth (MaxText-style) —
 this keeps 512-device dry-run compiles tractable and is also what you
-deploy.  Per-layer heterogeneity (gemma2/hymba window pattern) rides
-through the scan as a traced (L,) metadata array.
+deploy.  Layers of different kinds (leading dense layers before expert
+layers) form stacks of their own, each its own scan, in order.  Within
+a stack, a periodic window pattern reaches each layer as a static int;
+an aperiodic one (hymba) rides through the scan as a traced (L,)
+metadata array.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +44,7 @@ PyTree = Any
 # ---------------------------------------------------------------------------
 
 
-def _layer_init(cfg: ModelConfig, rng) -> Dict:
+def _layer_init(cfg: ModelConfig, rng, dense: bool = False) -> Dict:
     ks = jax.random.split(rng, 8)
     p: Dict[str, Any] = {"ln1": L.rmsnorm_init(cfg.d_model, "ln")}
     if not cfg.attn_free:
@@ -50,7 +55,7 @@ def _layer_init(cfg: ModelConfig, rng) -> Dict:
         p["ssd"] = L.ssd_init(ks[1], cfg, "ssd")
     if cfg.d_ff:
         p["ln2"] = L.rmsnorm_init(cfg.d_model, "ln")
-        if cfg.moe_experts:
+        if cfg.moe_experts and not dense:
             p["moe"] = L.moe_init(ks[2], cfg, "moe")
             if cfg.moe_dense_residual:
                 p["mlp"] = L.mlp_init(ks[3], cfg.d_model, cfg.d_ff, "mlp")
@@ -74,14 +79,30 @@ def _enc_layer_init(cfg: ModelConfig, rng) -> Dict:
     }
 
 
+def layer_stacks(cfg: ModelConfig) -> List[Tuple[str, int, int]]:
+    """(parameter key, first layer, end layer) of each stack of equal
+    layers, in order: the leading dense layers (``dense_layers``), then
+    the rest (``layers``)."""
+    nd = cfg.first_dense_layers
+    if not nd:
+        return [("layers", 0, cfg.n_layers)]
+    return [("dense_layers", 0, nd), ("layers", nd, cfg.n_layers)]
+
+
 def init_params(cfg: ModelConfig, rng) -> PyTree:
     k_emb, k_layers, k_enc, k_f = jax.random.split(rng, 4)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
     params = {
         "embed": L.embed_init(k_emb, cfg.vocab, cfg.d_model),
-        "layers": jax.vmap(lambda k: _layer_init(cfg, k))(layer_keys),
         "final_ln": L.rmsnorm_init(cfg.d_model, "ln"),
     }
+    for key, lo, hi in layer_stacks(cfg):
+        dense = key == "dense_layers"
+        params[key] = jax.vmap(lambda k, d=dense: _layer_init(cfg, k, d))(
+            layer_keys[lo:hi]
+        )
+    if not cfg.tie_embed:
+        params["head"] = L.embed_init(k_f, cfg.vocab, cfg.d_model, "head")
     if cfg.enc_layers:
         enc_keys = jax.random.split(k_enc, cfg.enc_layers)
         params["enc_layers"] = jax.vmap(lambda k: _enc_layer_init(cfg, k))(enc_keys)
@@ -106,8 +127,11 @@ def layer_windows(cfg: ModelConfig) -> np.ndarray:
     stay concrete under jax.eval_shape; scan converts it on use.
     """
     Ln = cfg.n_layers
-    if cfg.alt_local_global and cfg.local_window:
-        w = [(cfg.local_window if i % 2 == 0 else 0) for i in range(Ln)]
+    if cfg.global_every and cfg.local_window:
+        # every Nth layer global, the others sliding (gemma2 alternates
+        # with N = 2, trinity runs 3:1 with N = 4)
+        N = cfg.global_every
+        w = [(0 if (i + 1) % N == 0 else cfg.local_window) for i in range(Ln)]
     elif cfg.hybrid and cfg.local_window:
         # hymba: global attention on first / middle / last layers
         glb = {0, Ln // 2, Ln - 1}
@@ -178,8 +202,10 @@ def _attn_full(cfg, p, x, pos, window, chunk=1024):
     q = shd.shard(q, "act_heads", hd_dims)
     k = shd.shard(k, "act_kv_heads", hd_dims)
     v = shd.shard(v, "act_kv_heads", hd_dims)
-    q = _rope_q(cfg, q, pos)
-    k = _rope_q(cfg, k, pos)
+    if cfg.qk_norm:
+        q = L.rmsnorm(q, p["q_norm"], cfg.norm_eps)
+        k = L.rmsnorm(k, p["k_norm"], cfg.norm_eps)
+    q, k = _rope_local(cfg, q, k, pos, window)
     out = L.chunked_attention(
         q, k, v,
         causal=True,
@@ -188,7 +214,21 @@ def _attn_full(cfg, p, x, pos, window, chunk=1024):
         chunk=min(chunk, S),
     )
     out = out.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.head_dim)
+    if cfg.attn_gate:
+        out = out * jax.nn.sigmoid(L.dense_op(x, p["wg"]))
     return L.dense_op(out, p["wo"]), (k, v)
+
+
+def _rope_local(cfg, q, k, pos, window):
+    """Rotary on q and k; with ``nope_global`` only on sliding-window
+    layers (a traced window selects per layer)."""
+    traced = isinstance(window, jax.core.Tracer)
+    if cfg.nope_global and not traced and not window:
+        return q, k
+    rq, rk = _rope_q(cfg, q, pos), _rope_q(cfg, k, pos)
+    if cfg.nope_global and traced:
+        return jnp.where(window > 0, rq, q), jnp.where(window > 0, rk, k)
+    return rq, rk
 
 
 def _layer_fwd(
@@ -220,8 +260,8 @@ def _layer_fwd(
     if cfg.d_ff:
         h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
         f = jnp.zeros_like(x)
-        if cfg.moe_experts:
-            f = f + L.moe(p["moe"], h2, cfg.moe_top_k, cfg.moe_capacity_factor, act=cfg.act)
+        if "moe" in p:
+            f = f + L.moe(p["moe"], h2, cfg)
             if cfg.moe_dense_residual:
                 f = f + L.mlp(p["mlp"], h2, cfg.act)
         else:
@@ -298,11 +338,23 @@ def forward(
         if cfg.enc_layers
         else None
     )
+    for key, lo, hi in layer_stacks(cfg):
+        x = _scan_layers(cfg, params[key], x, pos, windows[lo:hi], cross, remat)
+    x = L.rmsnorm(x, params["final_ln"], cfg.norm_eps)
+    logits = L.unembed(x, params["embed" if cfg.tie_embed else "head"])
+    if cfg.logit_softcap:
+        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+    return shd.shard(logits, "logits")
 
-    # Static-window scan: when the per-layer window pattern is periodic the
-    # window reaches each layer as a Python int closed over the scan body
-    # (the attention dispatch hook needs a concrete value to serve the
-    # fused kernel); only aperiodic patterns trace it through the scan.
+
+def _scan_layers(cfg, layers, x, pos, windows, cross, remat):
+    """One stack of equal layers as one scan.
+
+    Static-window scan: when the stack's window pattern is periodic the
+    window reaches each layer as a Python int closed over the scan body
+    (the attention dispatch hook needs a concrete value to serve the
+    fused kernel); only aperiodic patterns trace it through the scan.
+    """
     period = window_period(windows)
     if period is None:
 
@@ -312,7 +364,7 @@ def forward(
             x, _, _, _ = _layer_fwd(cfg, p, x, pos, w, cross_fn=cross)
             return x, None
 
-        xs = (params["layers"], windows)
+        xs = (layers, windows)
     else:
         win_static = [int(windows[j]) or None for j in range(period)]
 
@@ -329,11 +381,7 @@ def forward(
                 )
             return x, None
 
-        xs = (
-            params["layers"]
-            if period == 1
-            else _stack_period(params["layers"], period)
-        )
+        xs = layers if period == 1 else _stack_period(layers, period)
 
     if remat:
         # save only layer boundaries; recompute internals in backward
@@ -341,11 +389,7 @@ def forward(
             step, policy=jax.checkpoint_policies.nothing_saveable
         )
     x, _ = jax.lax.scan(step, x, xs)
-    x = L.rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    logits = L.unembed(x, params["embed"])
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return shd.shard(logits, "logits")
+    return x
 
 
 def loss_fn(cfg: ModelConfig, params: PyTree, batch: Dict) -> jnp.ndarray:
@@ -374,9 +418,23 @@ def loss_fn(cfg: ModelConfig, params: PyTree, batch: Dict) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def _refuse_cached_steps(cfg: ModelConfig) -> None:
+    """The cache-carrying steps (prefill into a cache, decode, serve) scan
+    one homogeneous layer stack and unembed through the tied table; a
+    stack with leading dense layers or an output head of its own runs the
+    full-sequence :func:`forward` only."""
+    if cfg.first_dense_layers or not cfg.tie_embed:
+        raise NotImplementedError(
+            f"{cfg.name}: decode and serving are not implemented for a "
+            "layer stack with leading dense layers or an untied output "
+            "head; only the full-sequence forward runs"
+        )
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> PyTree:
     """Decode cache.  Sliding-window layers only allocate the window (ring
     buffer) — this is what makes hymba's 512k decode bounded."""
+    _refuse_cached_steps(cfg)
     dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     cache: Dict[str, Any] = {"pos": jnp.zeros((), dtype=jnp.int32)}
     Ln = cfg.n_layers
@@ -417,6 +475,7 @@ def prefill(
     frames: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, PyTree]:
     """Process the prompt, fill the cache, return last-position logits."""
+    _refuse_cached_steps(cfg)
     if embeds is not None:
         x = embeds.astype(jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
     else:
@@ -559,6 +618,7 @@ def serve_step(
     are supported (SSD state and encoder cross-attention have no
     variable-width chunk step); ``ServeConfig.resolved_for`` routes other
     families back to ``decode_step``."""
+    _refuse_cached_steps(cfg)
     if cfg.attn_free or cfg.ssm_state or cfg.enc_layers:
         raise NotImplementedError(
             "serve_step needs a pure-attention decoder; use decode_step"
@@ -623,7 +683,7 @@ def serve_step(
         if cfg.d_ff:
             h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
             if cfg.moe_experts:
-                f = L.moe(p["moe"], h2, cfg.moe_top_k, cfg.moe_capacity_factor, act=cfg.act)
+                f = L.moe(p["moe"], h2, cfg)
                 if cfg.moe_dense_residual:
                     f = f + L.mlp(p["mlp"], h2, cfg.act)
             else:
@@ -709,6 +769,7 @@ def decode_step(
     per-lane lengths).  Like forward()/prefill(), periodic per-layer
     window patterns close Python-int windows over the scan body so the
     decode attention dispatch hook sees static windows."""
+    _refuse_cached_steps(cfg)
     x = L.embed(tokens, params["embed"]) * math.sqrt(cfg.d_model)
     B = x.shape[0]
     p_now = cache["pos"]
@@ -784,7 +845,7 @@ def decode_step(
             h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
             f = jnp.zeros_like(x)
             if cfg.moe_experts:
-                f = f + L.moe(p["moe"], h2, cfg.moe_top_k, cfg.moe_capacity_factor, act=cfg.act)
+                f = f + L.moe(p["moe"], h2, cfg)
                 if cfg.moe_dense_residual:
                     f = f + L.mlp(p["mlp"], h2, cfg.act)
             else:

@@ -64,10 +64,13 @@ def decision_site_key(inst: Instruction) -> Optional[str]:
         return f"tile/extent={extent}/n={n}/max={maxin}"
     if inst.name == "sample_mxu_block":
         shape = ",".join(str(d) for d in inst.attrs["shape"])
-        return (
+        site = (
             f"mxu/shape={shape}/itemsize={inst.attrs['itemsize']}"
             f"/bias={int(inst.attrs.get('bias', False))}"
         )
+        if inst.attrs.get("groups"):
+            site += f"/groups={inst.attrs['groups']}"
+        return site
     if inst.name == "sample_categorical":
         cands = ",".join(str(c) for c in inst.attrs.get("candidates", []))
         return f"cat/candidates={cands}"

@@ -20,7 +20,8 @@ import jax
 import numpy as np
 
 from ...backends.registry import get_backend, resolve_backend_spec
-from ...core.tir import PrimFunc, random_inputs
+from ...core.tir import PrimFunc
+from ...core.workloads import workload_inputs
 from ...core.validator import validate_trace
 from ...obs import emit, span, trace_enabled
 from .hashing import structural_hash
@@ -154,7 +155,7 @@ class LocalRunner(Runner):
         key = func.name + str(tuple(b.shape for b in func.inputs))
         if key not in self._inputs_cache:
             self._inputs_cache[key] = {
-                k: jax.device_put(v) for k, v in random_inputs(func, 0).items()
+                k: jax.device_put(v) for k, v in workload_inputs(func, 0).items()
             }
         return self._inputs_cache[key]
 

@@ -58,7 +58,7 @@ def _measure_worker(payload: dict) -> dict:
         import jax
 
         from ...backends.registry import get_backend
-        from ...core.tir import random_inputs
+        from ...core.workloads import workload_inputs
         from ...core.trace import Trace
         from ...core.validator import validate_trace
         from .local import time_artifact
@@ -84,7 +84,7 @@ def _measure_worker(payload: dict) -> dict:
         ins = _WORKER_INPUT_CACHE.get(ins_key)
         if ins is None:
             ins = {
-                k: jax.device_put(x) for k, x in random_inputs(func, 0).items()
+                k: jax.device_put(x) for k, x in workload_inputs(func, 0).items()
             }
             _WORKER_INPUT_CACHE[ins_key] = ins
         build_s = time.perf_counter() - t_start

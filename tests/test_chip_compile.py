@@ -2,7 +2,8 @@
 
 Nothing runs: each test lowers one kernel at smollm-135m widths
 (d_model=576, d_ff=1536, vocab 49152, 9 query / 3 kv heads, head_dim 64),
-or the dense kernel at stablelm-3b widths, and compiles it with the TPU compiler for a chip that is described, not
+the dense kernel at stablelm-3b widths or the grouped kernel at
+trinity-mini's, and compiles it with the TPU compiler for a chip that is described, not
 attached.  This is what the Pallas interpreter cannot check: Mosaic's
 block-shape and layout rules.  Each kernel also carries its identity into
 the compiled op, as the profiler's trace prints it: the op is named by its
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.backends.pallas_backend import MatmulShape, snap_blocks
 from repro.kernels import flash_attention as fa
+from repro.kernels import grouped_matmul as gm
 from repro.kernels import matmul as mm
 from repro.kernels import softmax as sm
 
@@ -109,6 +111,26 @@ def test_dense_largest_legal_block(one_chip, op, dtype):
     _compile(
         lambda x, w: mm.matmul(x, w, block_sizes=blocks, interpret=False),
         one_chip, ((m, k), dtype), ((k, n), dtype),
+    )
+
+
+# (k, n) of trinity-mini's expert projections: 2 x 8192 tokens, 8 of 128
+# experts each, so m = 131072 (token, slot) rows in 128 groups
+TRINITY_GROUPED = {"up": (2048, 1024), "down": (1024, 2048)}
+
+
+@pytest.mark.parametrize("op", sorted(TRINITY_GROUPED))
+def test_grouped_largest_legal_block(one_chip, op):
+    # the grouped block that takes the most VMEM compiles within the
+    # kernel's default scoped VMEM, group bookkeeping prefetched
+    k, n = TRINITY_GROUPED[op]
+    shape = MatmulShape(1, 131072, n, k, 2, groups=128)
+    blocks = max(shape.legal_blocks(), key=shape.vmem_bytes)
+    _compile(
+        lambda x, w, g: gm.grouped_matmul(x, w, g, block_sizes=blocks,
+                                          interpret=False),
+        one_chip, ((131072, k), jnp.bfloat16), ((128, k, n), jnp.bfloat16),
+        ((128,), jnp.int32),
     )
 
 
@@ -199,6 +221,12 @@ IDENTITIES = {
          ((SLOTS * KV_LEN // PAGE, KVH, PAGE, HD), F32),
          ((SLOTS, KV_LEN // PAGE), jnp.int32), ((SLOTS, KV_LEN), F32)],
         str(PAGE), "float32",
+    ),
+    "grouped_dense": (
+        lambda x, w, g: gm.grouped_matmul(x, w, g, block_sizes=(128, 128, 256),
+                                          interpret=False, task=TASK),
+        [((1024, 256), BF16), ((8, 256, 128), BF16), ((8,), jnp.int32)],
+        "128,128,256", "bfloat16",
     ),
     "row_softmax": (
         lambda x: sm.row_softmax(x, block_rows=64, interpret=False, task=TASK),
