@@ -397,7 +397,7 @@ def lower_attention(
     the sequence length — the same sampled-vs-snapped provenance contract
     as the matmul tiles.
     """
-    from ..kernels.flash_attention import flash_attention
+    from ..kernels.flash_attention import flash_attention, visit_schedule
 
     func = sch.func
     Q = func.inputs[0]
@@ -408,7 +408,9 @@ def lower_attention(
         (s, s), sampled or DEFAULT_ATTN_BLOCKS, (SUBLANE, SUBLANE)
     )
     bq, bkv = blocks
-    _check_grid(b * kvh * g * (s // bq) * (s // bkv), blocks)
+    _check_grid(
+        b * kvh * g * visit_schedule(s, bq, bkv, causal, window).steps, blocks
+    )
     meta = _block_meta("flash_attention", sampled, blocks)
 
     def fn(inputs: Dict):

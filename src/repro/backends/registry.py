@@ -189,7 +189,10 @@ class PallasBackend(Backend):
         ``attention`` record the dispatch layer serves the fully-lowered
         kernel (db-tuned blocks) and never reaches here.  Blocks snap to
         the aligned divisor of the sequence length nearest the MXU-native
-        128 tile — the pre-tuning fixed default.
+        128 tile — the pre-tuning fixed default.  The kernel visits only
+        the (q, kv) block pairs its mask admits: a window's band, one q
+        block a row, or a causal triangle folded two q blocks a row
+        (:func:`~repro.kernels.flash_attention.visit_schedule`).
         """
         from ..kernels.flash_attention import flash_attention
 

@@ -7,17 +7,30 @@ Online-softmax attention with BlockSpec-tiled Q/K/V staging, supporting:
   * GQA via BlockSpec index maps (kv head = q head // group) — no
     materialized K/V repetition.
 
-The kv grid dimension is sequential; running (m, l, acc) statistics live in
-VMEM scratch.  Block sizes (bq, bkv) are MetaSchedule-tunable.
+The grid visits only the (q block, kv block) pairs the mask admits
+(:func:`visit_schedule`): each q block runs over the contiguous kv range
+its causal or window mask reaches, in rising kv order, as one run of
+steps on the sequential last grid axis; running (m, l, acc) statistics
+live in VMEM scratch across the run.  A window's runs are laid out one q
+block a row (a band); a causal triangle's are folded in pairs, the short
+run of q block r then the long run of q block nq-1-r, so that every row
+has about the same length; a full square keeps one q block a row over
+every kv block.  Steps a row has beyond its runs repeat the last kv block
+(no new DMA) and skip the compute.  The layout follows from the sequence
+length, the blocks and the mask alone, computed from the grid position by
+integer arithmetic.  Block sizes (bq, bkv) are MetaSchedule-tunable, and
+set how many steps the schedule visits.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -47,6 +60,97 @@ def best_divisor(n: int, target: int, align: int) -> int:
     return best
 
 
+@dataclasses.dataclass(frozen=True)
+class VisitSchedule:
+    """Which (q block, kv block) pair each grid step of the prefill kernel
+    visits.  Grid rows run along ``pl.program_id(1)``, the steps of a row
+    along ``pl.program_id(2)``.  Q block ``qi`` needs the kv blocks
+    ``first_kv(qi) .. last_kv(qi)``; a row holds the run of one q block
+    (``folded`` False) or of q blocks ``r`` and ``nq - 1 - r`` in turn.
+
+    The methods take ``xp`` — ``numpy`` or ``jax.numpy`` — so the same
+    arithmetic serves the kernel's index maps and body on traced grid
+    positions, and plain arrays of positions elsewhere.
+    """
+
+    nq: int
+    nkv: int
+    bq: int
+    bkv: int
+    causal: bool
+    window: Optional[int]
+    folded: bool = False
+    row_len: int = 0
+
+    @property
+    def rows(self) -> int:
+        return (self.nq + 1) // 2 if self.folded else self.nq
+
+    @property
+    def steps(self) -> int:
+        """Grid steps of one (batch, head): the steps the kernel visits."""
+        return self.rows * self.row_len
+
+    @property
+    def visited(self) -> str:
+        """``"<steps visited>/<steps of the full grid>"``."""
+        return f"{self.steps}/{self.nq * self.nkv}"
+
+    def first_kv(self, qi, xp):
+        if self.window is None:
+            return 0
+        # the earliest key any query of the block reaches is
+        # qi*bq - window + 1; the numerator stays non-negative
+        return xp.maximum(qi * self.bq - (self.window - 1), 0) // self.bkv
+
+    def last_kv(self, qi, xp):
+        if not self.causal:
+            return self.nkv - 1
+        return ((qi + 1) * self.bq - 1) // self.bkv
+
+    def at(self, r, j, xp):
+        """(qi, ki, valid, first, last) at row ``r``, step ``j``: the q and
+        kv block (``ki`` held at the run's last block past its end),
+        whether the pair is computed, and whether the step is the first or
+        last of q block ``qi``'s steps."""
+        if self.folded:
+            q2 = self.nq - 1 - r
+            len1 = self.last_kv(r, xp) - self.first_kv(r, xp) + 1
+            paired = q2 != r
+            second = paired & (j >= len1)
+            qi = xp.where(second, q2, r)
+            off = xp.where(second, j - len1, j)
+            last = xp.where(
+                paired & ~second, j == len1 - 1, j == self.row_len - 1
+            )
+        else:
+            qi, off, last = r, j, j == self.row_len - 1
+        ki = self.first_kv(qi, xp) + off
+        hi = self.last_kv(qi, xp)
+        return qi, xp.minimum(ki, hi), ki <= hi, off == 0, last
+
+
+def visit_schedule(
+    S: int, bq: int, bkv: int, causal: bool, window: Optional[int]
+) -> VisitSchedule:
+    """The visit schedule of a prefill attention over ``S`` positions in
+    (bq, bkv) blocks: one q block a row, or pairs of q blocks folded into
+    one row, whichever takes fewer steps.  A band (window) keeps one a
+    row; a causal triangle folds; a full square keeps the whole grid."""
+    base = VisitSchedule(S // bq, S // bkv, bq, bkv, causal, window)
+    qi = np.arange(base.nq)
+    runs = np.broadcast_to(
+        base.last_kv(qi, np) - base.first_kv(qi, np) + 1, qi.shape
+    )
+    rows = (base.nq + 1) // 2
+    pairs = np.where(
+        qi[:rows] == qi[::-1][:rows], runs[:rows], runs[:rows] + runs[::-1][:rows]
+    )
+    band = dataclasses.replace(base, row_len=int(runs.max()))
+    fold = dataclasses.replace(base, folded=True, row_len=int(pairs.max()))
+    return fold if fold.steps < band.steps else band
+
+
 def _attn_kernel(
     q_ref,
     k_ref,
@@ -56,49 +160,49 @@ def _attn_kernel(
     l_ref,
     acc_ref,
     *,
-    nkv: int,
-    bq: int,
-    bkv: int,
+    sched: VisitSchedule,
     scale: float,
-    causal: bool,
-    window: Optional[int],
     softcap: Optional[float],
 ):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    qi, ki, valid, first, last = sched.at(
+        pl.program_id(1), pl.program_id(2), jnp
+    )
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]  # (bq, d)
-    k = k_ref[0]  # (bkv, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
+    @pl.when(valid)
+    def _step():
+        bq, bkv = sched.bq, sched.bkv
+        q = q_ref[0]  # (bq, d)
+        k = k_ref[0]  # (bkv, d)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
 
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-    k_pos = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-    mask = jnp.ones((bq, bkv), dtype=bool)
-    if causal:
-        mask = mask & (k_pos <= q_pos)
-    if window is not None:
-        mask = mask & (q_pos - k_pos < window)
-    s = jnp.where(mask, s, NEG_INF)
+        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
+        k_pos = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+        mask = jnp.ones((bq, bkv), dtype=bool)
+        if sched.causal:
+            mask = mask & (k_pos <= q_pos)
+        if sched.window is not None:
+            mask = mask & (q_pos - k_pos < sched.window)
+        s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
+        )
+        m_ref[...] = m_new
 
-    @pl.when(ki == nkv - 1)
+    @pl.when(last)
     def _done():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
@@ -127,27 +231,20 @@ def flash_attention(
     # different-shaped relative of this call
     bq = best_divisor(S, min(block_q, S), SUBLANE)
     bkv = best_divisor(S, min(block_kv, S), SUBLANE)
-    nq, nkv = S // bq, S // bkv
+    sched = visit_schedule(S, bq, bkv, causal, window)
     kernel = functools.partial(
-        _attn_kernel,
-        nkv=nkv,
-        bq=bq,
-        bkv=bkv,
-        scale=scale,
-        causal=causal,
-        window=window,
-        softcap=softcap,
+        _attn_kernel, sched=sched, scale=scale, softcap=softcap
     )
-    grid = (B * H, 1, nq, nkv)  # (batch*head, unit, q blocks, kv blocks)
+    grid = (B * H, sched.rows, sched.row_len)  # (batch*head, rows, steps)
 
-    def qmap(bh, _, qi, ki):
-        return (bh, qi, 0)
+    def qmap(bh, r, j):
+        return (bh, sched.at(r, j, jnp)[0], 0)
 
-    def kvmap(bh, _, qi, ki):
+    def kvmap(bh, r, j):
         # GQA: q head bh%H maps to kv head (bh%H)//G
         b = bh // H
         h = bh % H
-        return (b * KVH + h // G, ki, 0)
+        return (b * KVH + h // G, sched.at(r, j, jnp)[1], 0)
 
     out = pl.pallas_call(
         kernel,
@@ -165,13 +262,16 @@ def flash_attention(
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         )
         if not interpret
         else None,
         interpret=interpret,
         name="flash_attention",
-        metadata=kernel_metadata(task, (bq, bkv), q.dtype),
+        metadata={
+            **kernel_metadata(task, (bq, bkv), q.dtype),
+            "visited": sched.visited,
+        },
     )(
         q.reshape(B * H, S, D),
         k.reshape(B * KVH, S, D),

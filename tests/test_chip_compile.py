@@ -2,13 +2,15 @@
 
 Nothing runs: each test lowers one kernel at smollm-135m widths
 (d_model=576, d_ff=1536, vocab 49152, 9 query / 3 kv heads, head_dim 64),
-the dense kernel at stablelm-3b widths or the grouped kernel at
-trinity-mini's, and compiles it with the TPU compiler for a chip that is described, not
-attached.  This is what the Pallas interpreter cannot check: Mosaic's
-block-shape and layout rules.  Each kernel also carries its identity into
-the compiled op, as the profiler's trace prints it: the op is named by its
-template and its ``kernel_metadata`` holds the tuned record's workload key,
-blocks and dtype.  (The ``ssd`` kernel is left out: Mosaic has no lowering
+the dense kernel at stablelm-3b widths, the grouped kernel at
+trinity-mini's or the prefill flash kernel at both benchmark cells' shapes
+in bf16, and compiles it with the TPU compiler for a chip that is
+described, not attached.  This is what the Pallas interpreter cannot
+check: Mosaic's block-shape and layout rules.  Each kernel also carries
+its identity into the compiled op, as the profiler's trace prints it: the
+op is named by its template and its ``kernel_metadata`` holds the tuned
+record's workload key, blocks and dtype (and the flash kernel's visit
+count).  (The ``ssd`` kernel is left out: Mosaic has no lowering
 for its ``cumsum``.)  The topology is described inside a fixture
 only, so a test worker that is never given this file never loads the TPU
 library.
@@ -78,6 +80,7 @@ def _identity(hlo):
 
 
 F32 = jnp.float32
+BF16 = jnp.bfloat16
 
 
 @pytest.mark.parametrize(
@@ -154,6 +157,30 @@ def test_prefill_flash_attention(one_chip):
     )
 
 
+# (batch, heads, kv heads, seq, head_dim, window) of the benchmark cells'
+# prefill attention: stablelm-3b at 4 x 2048, and trinity-mini at 2 x 8192
+# on its sliding and its global layers; with the visit schedule's count
+BENCH_ATTENTION = {
+    "stablelm": ((4, 32, 32, 2048, 80, None), "136/256"),
+    "trinity-sliding": ((2, 32, 4, 8192, 128, 2048), "1088/4096"),
+    "trinity-global": ((2, 32, 4, 8192, 128, None), "2080/4096"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BENCH_ATTENTION))
+def test_prefill_flash_attention_bench_shapes(one_chip, cell):
+    (b, h, kvh, s, hd, window), visited = BENCH_ATTENTION[cell]
+    name, meta = _identity(_compile(
+        lambda q, k, v: fa.flash_attention(q, k, v, window=window,
+                                           interpret=False),
+        one_chip,
+        ((b, h, s, hd), BF16), ((b, kvh, s, hd), BF16),
+        ((b, kvh, s, hd), BF16),
+    ))
+    assert name == "flash_attention"
+    assert meta["visited"] == visited
+
+
 def test_decode_attention(one_chip):
     _compile(
         lambda q, k, v, b: fa.decode_flash_attention(q, k, v, b, interpret=False),
@@ -184,7 +211,6 @@ def test_row_softmax(one_chip):
     )
 
 
-BF16 = jnp.bfloat16
 TASK = "task/of=the_record"
 
 # template -> (kernel called with task=TASK, operand shapes, blocks, dtype)
@@ -235,12 +261,18 @@ IDENTITIES = {
 }
 
 
+# kernel-specific counters in the metadata: the flash kernel's visit
+# schedule at 2 q blocks of 64 over 1 kv block of 128
+COUNTERS = {"flash_attention": {"visited": "2/2"}}
+
+
 @pytest.mark.parametrize("template", sorted(IDENTITIES))
 def test_kernel_carries_its_identity(one_chip, template):
     fn, shapes, blocks, dtype = IDENTITIES[template]
     name, meta = _identity(_compile(fn, one_chip, *shapes))
     assert name == template
-    assert meta == {"task": TASK, "blocks": blocks, "dtype": dtype}
+    assert meta == {"task": TASK, "blocks": blocks, "dtype": dtype,
+                    **COUNTERS.get(template, {})}
 
 
 def test_kernel_outside_a_record_has_no_task(one_chip):

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, visit_schedule
 from repro.kernels.matmul import matmul
 from repro.kernels.ssd import ssd
 
@@ -71,6 +71,13 @@ class TestFlashAttentionKernel:
             (1, 8, 2, 128, 64, True, None, 30.0, 32, 64),
             (1, 2, 1, 256, 64, False, None, None, 128, 128),
             (2, 6, 3, 64, 16, True, 16, 20.0, 32, 32),
+            # folded causal grid, odd nq: 5 q blocks in 3 rows
+            (1, 2, 2, 320, 32, True, None, None, 64, 64),
+            # window band whose first two rows run past their diagonal
+            (1, 2, 1, 512, 32, True, 128, None, 64, 64),
+            # GQA with bq != bkv, causal and banded
+            (2, 4, 2, 256, 32, True, None, None, 32, 64),
+            (1, 6, 2, 384, 32, True, 96, None, 128, 64),
         ],
     )
     def test_variant_sweep(self, B, H, KVH, S, D, causal, win, cap, bq, bkv):
@@ -85,6 +92,90 @@ class TestFlashAttentionKernel:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3
         )
+
+
+def _admitted(S, bq, bkv, causal, window):
+    """The (q block, kv block) pairs holding a (query, key) pair the mask
+    admits, from the elementwise mask as ``ref.flash_attention`` builds it."""
+    d = np.arange(S)[:, None] - np.arange(S)[None, :]  # query - key
+    mask = np.ones((S, S), bool)
+    if causal:
+        mask &= d >= 0
+    if window is not None:
+        mask &= d < window
+    blocks = mask.reshape(S // bq, bq, S // bkv, bkv).any(axis=(1, 3))
+    return set(zip(*map(list, np.nonzero(blocks))))
+
+
+def _visits(sched):
+    """(qi, ki, valid, first, last) of every grid step, rows in order and
+    each row's steps in order."""
+    r, j = np.meshgrid(np.arange(sched.rows), np.arange(sched.row_len),
+                       indexing="ij")
+    cols = sched.at(r, j, np)
+    return list(zip(*(np.broadcast_to(c, r.shape).ravel().tolist()
+                      for c in cols)))
+
+
+class TestFlashSchedule:
+    """The prefill kernel's visit schedule, without running the kernel."""
+
+    @pytest.mark.parametrize(
+        "S,bq,bkv,causal,window",
+        [
+            (512, 64, 64, True, None),  # even nq, folded
+            (448, 64, 64, True, None),  # odd nq: the middle row runs alone
+            (512, 128, 64, True, None),  # bq = 2 bkv
+            (384, 128, 64, True, None),
+            (512, 64, 128, True, None),  # bkv = 2 bq
+            (768, 128, 256, True, None),
+            (512, 64, 64, True, 40),  # window under one block
+            (448, 64, 64, True, 100),  # window over one block
+            (512, 64, 64, True, 65),  # window one past a block edge
+            (512, 128, 64, True, 200),
+            (768, 128, 256, True, 300),
+            (384, 64, 128, True, 130),
+            (512, 64, 64, True, 512),  # window as long as S
+            (256, 64, 64, False, None),  # the full square
+            (256, 64, 32, False, 100),
+        ],
+    )
+    def test_visits_each_admitted_pair_once_in_order(
+        self, S, bq, bkv, causal, window
+    ):
+        sched = visit_schedule(S, bq, bkv, causal, window)
+        steps = _visits(sched)
+        assert len(steps) == sched.steps <= sched.nq * sched.nkv
+        computed = [(qi, ki) for qi, ki, valid, _, _ in steps if valid]
+        assert len(computed) == len(set(computed))
+        assert set(computed) == _admitted(S, bq, bkv, causal, window)
+        # each q block's steps are one contiguous run of one row, its
+        # computed kv blocks rising, flagged first and last at its ends
+        at = {}
+        for n, (qi, *_) in enumerate(steps):
+            at.setdefault(qi, []).append(n)
+        assert sorted(at) == list(range(sched.nq))
+        for qi, ns in at.items():
+            assert ns == list(range(ns[0], ns[-1] + 1))
+            assert len({n // sched.row_len for n in ns}) == 1
+            run = [steps[n] for n in ns]
+            kis = [ki for _, ki, valid, _, _ in run if valid]
+            assert kis == sorted(set(kis))
+            assert [f for *_, f, _ in run] == [True] + [False] * (len(run) - 1)
+            assert [l for *_, l in run] == [False] * (len(run) - 1) + [True]
+
+    @pytest.mark.parametrize(
+        "S,window,visited,computed",
+        [
+            (2048, None, "136/256", 136),  # stablelm-3b, causal
+            (8192, None, "2080/4096", 2080),  # trinity-mini, global layer
+            (8192, 2048, "1088/4096", 952),  # trinity-mini, sliding layers
+        ],
+    )
+    def test_benchmark_step_counts(self, S, window, visited, computed):
+        sched = visit_schedule(S, 128, 128, True, window)
+        assert sched.visited == visited
+        assert sum(valid for _, _, valid, _, _ in _visits(sched)) == computed
 
 
 class TestSSDKernel:
